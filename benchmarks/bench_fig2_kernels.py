@@ -4,9 +4,10 @@ Runs the paper's benchmark set (SLEEP, FMA32, STREAM, GRIDDER, DEGRIDDER,
 GEMM, JACOBI2D) instrumented with two stacked sensors, exactly like the
 paper's stacked decorators: the *measured* host sensor (cpuutil) and the
 *modeled* accelerator sensor (tpu — fed the kernel's own compiled cost
-analysis).  Kernels execute the Pallas path in interpret mode on CPU; the
-TPU energy numbers are the analytical model evaluated on each kernel's
-real FLOPs/bytes (kind labels make measured-vs-modeled explicit).
+analysis).  Kernels run compiled on a TPU and in Pallas interpret mode
+only on a CPU backend (the run prints which); the TPU energy numbers are
+the analytical model evaluated on each kernel's real FLOPs/bytes (kind
+labels make measured-vs-modeled explicit).
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ def _cost(fn, *args):
 
 
 def _run(name, fn, args, flops, bytes_, rows, repeats=3):
-    """cpu watts: measured over the interpret-mode run.  tpu watts: the
+    """cpu watts: measured over the run.  tpu watts: the
     model evaluated at the kernel's TPU-projected duration (roofline max
     of compute and HBM time) — i.e. what the chip would draw actually
     executing this kernel, which is what reproduces Fig. 2's contrast
@@ -51,6 +52,10 @@ def _run(name, fn, args, flops, bytes_, rows, repeats=3):
 def main(csv=False):
     rows = []
     key = jax.random.PRNGKey(0)
+    platform = jax.default_backend()
+    interpret = platform == "cpu"
+    print(f"# kernels: {'Pallas interpret mode' if interpret else 'compiled'}"
+          f" on {platform}")
 
     # SLEEP — idle power floor
     cpu = pmt.create("cpuutil")
@@ -66,14 +71,14 @@ def main(csv=False):
     x = jax.random.normal(key, (1024, 512), jnp.float32)
     # 1024 chained FMAs/element -> 512 FLOP/byte, past the v5e ridge
     # point (240), so the modeled kernel is compute-bound like the paper's
-    fn = lambda a: fma32(a, iters=1024, interpret=True)
+    fn = lambda a: fma32(a, iters=1024, interpret=interpret)
     f, b = 2.0 * x.size * 1024, 2.0 * x.size * 4
     _run("FMA32", fn, (x,), f, b, rows)
 
     from repro.kernels.stream.ops import stream_triad
     a = jax.random.normal(key, (4096, 512), jnp.float32)
     bb = jax.random.normal(key, (4096, 512), jnp.float32)
-    fn = lambda p, q: stream_triad(p, q, interpret=True)
+    fn = lambda p, q: stream_triad(p, q, interpret=interpret)
     f, by = 2.0 * a.size, 3.0 * a.size * 4
     _run("STREAM", fn, (a, bb), f, by, rows)
 
@@ -84,10 +89,10 @@ def main(csv=False):
     vis = jax.random.normal(key, (S, V, 2), jnp.float32)
     f = 8.0 * S * V * P
     by = 4.0 * (S * V * 4 + S * P * 2) * 4
-    _run("GRIDDER", lambda *z: gridder(*z, interpret=True), (lm, uv, vis),
+    _run("GRIDDER", lambda *z: gridder(*z, interpret=interpret), (lm, uv, vis),
          f, by, rows)
     sub = jax.random.normal(key, (S, P, 2), jnp.float32)
-    _run("DEGRIDDER", lambda *z: degridder(*z, interpret=True),
+    _run("DEGRIDDER", lambda *z: degridder(*z, interpret=interpret),
          (lm, uv, sub), f, by, rows)
 
     from repro.kernels.gemm.ops import gemm
@@ -95,13 +100,13 @@ def main(csv=False):
     n = jax.random.normal(key, (512, 512), jnp.float32)
     f, by = 2.0 * 512 ** 3, 3.0 * 512 * 512 * 4
     _run("GEMM", lambda p, q: gemm(p, q, block_m=256, block_n=256,
-                                   block_k=256, interpret=True), (m, n),
+                                   block_k=256, interpret=interpret), (m, n),
          f, by, rows)
 
     from repro.kernels.jacobi2d.ops import jacobi2d
     j = jax.random.normal(key, (1024, 512), jnp.float32)
     f, by = 5.0 * j.size, 2.0 * j.size * 4
-    _run("JACOBI2D", lambda p: jacobi2d(p, interpret=True), (j,), f, by,
+    _run("JACOBI2D", lambda p: jacobi2d(p, interpret=interpret), (j,), f, by,
          rows)
 
     print("# Fig.2 — PMT stacked measurement: CPU (measured) + "

@@ -21,6 +21,10 @@ from repro.kernels.stream.ops import stream_triad
 def main():
     cpu = pmt.create("cpuutil")
     tpu = TpuCostModelSensor.create()
+    platform = jax.default_backend()
+    interpret = platform == "cpu"
+    print(f"kernels: {'Pallas interpret mode' if interpret else 'compiled'}"
+          f" on {platform}")
 
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (1024, 512), jnp.float32)
@@ -34,13 +38,13 @@ def main():
         for name, fn, (fl, by) in [
             ("IDLE", lambda: time.sleep(0.6), (0, 0)),
             ("FMA32", lambda: jax.block_until_ready(
-                fma32(x, iters=128, interpret=True)),
+                fma32(x, iters=128, interpret=interpret)),
              (2.0 * x.size * 128, 2.0 * x.size * 4)),
             ("STREAM", lambda: jax.block_until_ready(
-                stream_triad(a, b, interpret=True)),
+                stream_triad(a, b, interpret=interpret)),
              (2.0 * a.size, 3.0 * a.size * 4)),
             ("GEMM", lambda: jax.block_until_ready(
-                gemm(m, m, interpret=True)),
+                gemm(m, m, interpret=interpret)),
              (2.0 * 512 ** 3, 3.0 * 512 * 512 * 4)),
         ]:
             t0 = time.perf_counter()

@@ -20,9 +20,22 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
-from repro.core.energy_model import EnergyModel
+from repro.core.energy_model import EnergyModel, hardware_for
 from repro.core.registry import register_backend
 from repro.core.sensor import Sample, Sensor
+
+
+def _attached_model() -> EnergyModel:
+    """The model of the chip this process drives.  On a TPU backend its
+    peaks come from the device kind, and an unknown kind raises.  Off
+    the TPU there is no chip to read: the v5e defaults model one (CPU
+    rehearsals and tests; pass an explicit ``EnergyModel`` for another).
+    """
+    import jax     # the PMT core imports no JAX; only this sensor asks it
+
+    if jax.default_backend() != "tpu":
+        return EnergyModel()
+    return EnergyModel(hw=hardware_for(jax.devices()[0].device_kind))
 
 
 class TpuCostModelSensor(Sensor):
@@ -33,7 +46,7 @@ class TpuCostModelSensor(Sensor):
     def __init__(self, model: Optional[EnergyModel] = None, chips: int = 1,
                  clock: Optional[Callable[[], float]] = None):
         super().__init__(clock=clock)
-        self._model = model or EnergyModel()
+        self._model = model or _attached_model()
         self._chips = int(chips)
         self._acc_lock = threading.Lock()
         self._dynamic_joules = 0.0      # total accounted dynamic energy

@@ -38,8 +38,8 @@ class HardwareSpec:
     peak_w: float              # max sustained board power
 
 
-# Roofline constants fixed by the brief: 197 TFLOP/s bf16, 819 GB/s HBM,
-# ~50 GB/s/link ICI. HBM 16 GB per v5e chip.
+# Published v5e peaks (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s
+# bf16, 819 GB/s HBM, 16 GB HBM per chip; ~50 GB/s/link ICI.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops=197e12,
@@ -49,6 +49,24 @@ TPU_V5E = HardwareSpec(
     idle_w=60.0,
     peak_w=200.0,
 )
+
+# Chips keyed by ``jax.Device.device_kind`` as the TPU runtime reports it.
+HARDWARE_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a device kind.  A kind not in the
+    table is an error, never a default: peaks of another chip would
+    model its joules wrong without a sign."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r} (known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)})") from None
 
 
 @dataclasses.dataclass(frozen=True)

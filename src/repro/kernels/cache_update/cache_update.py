@@ -7,14 +7,23 @@ position counter, so one decode step writes row ``b``'s new key/value at
 stock lowering is a batch of B separate single-row updates (or a one-hot
 scatter that touches the whole cache).  This kernel does the write as a
 true scatter: the grid walks the batch, the output BlockSpec's index map
-reads the slot from scalar-prefetch SMEM, and each program DMAs exactly
-one (1, 1, F) row into place.  The cache operand is aliased to the
-output, so untouched rows are never copied.
+reads the slot from scalar-prefetch SMEM, and each program writes its
+row in place.  The cache operand is aliased to the output, so untouched
+rows are never copied.
 
-Layout note: callers flatten trailing dims to one lane axis F
-(``ops.cache_update`` handles the reshape).  On real TPUs F should be a
-multiple of 128 for an aligned store; the serve path's correctness gate
-runs in interpret mode where no such constraint applies.
+Two block forms, chosen from the cache's rank, because the TPU tiles
+the last two dims of an array by (8, 128) and a block must either fill
+a tile or span the whole dim:
+
+  * **row blocks** — caches with two or more trailing dims per slot
+    (attention K/V ``(B, C, KVH, hd)``, quantized codes): the slot axis
+    is a major dim, so a ``(1, 1, KVH, hd)`` block is legal and the
+    program writes exactly one row.
+  * **aligned read-modify-write blocks** — caches with one trailing dim
+    (per-row scales ``(B, C, KVH)``, the MLA latent ``(B, C, r+rope)``):
+    the slot axis is the sublane dim, so the block covers ``R`` slots
+    (8, or the whole axis when 8 does not divide it), is read in
+    through the aliased input, and only the target row is replaced.
 """
 from __future__ import annotations
 
@@ -28,21 +37,149 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import quant
 
 
-def _scatter_kernel(slots_ref, new_ref, cache_ref, out_ref):
-    # cache_ref is the aliased full cache (never read): the alias keeps
-    # every row this program does not own; only the slot row is written.
-    del slots_ref, cache_ref
-    out_ref[...] = new_ref[...]
+def _rows_per_block(shape) -> int:
+    """Slots per block for a cache ``shape`` = (N, slots, *rest)."""
+    n = shape[1]
+    if len(shape) >= 4:
+        return 1
+    return 8 if n % 8 == 0 else n
+
+
+def _put(out_ref, base_ref, row, r, first=True):
+    """Land ``row`` (the slot's values, shaped like one slot of the
+    block) at slot ``r`` of the out block.  Row blocks are overwritten;
+    aligned blocks start from the cache's current rows (``base_ref``) on
+    the first visit and keep earlier writes on consecutive revisits."""
+    rows = out_ref.shape[1]
+    if rows == 1:
+        out_ref[0, 0] = row.astype(out_ref.dtype)
+        return
+
+    if first is True:
+        out_ref[...] = base_ref[...]
+    else:
+        @pl.when(first)
+        def _():
+            out_ref[...] = base_ref[...]
+
+    ids = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
+    out_ref[0] = jnp.where(ids == r, row[None].astype(out_ref.dtype),
+                           out_ref[0])
+
+
+def _cache_spec(shape, index):
+    """BlockSpec over a cache ``shape`` whose slot block is picked by
+    ``index(*grid, *scalars) -> (lead, slot)``.  Also returns the block
+    rows, and the spec under which the aliased cache is passed in (read
+    by aligned blocks, untouched by row blocks)."""
+    rows = _rows_per_block(shape)
+    tail = (0,) * (len(shape) - 2)
+
+    def index_map(*args):
+        lead, slot = index(*args)
+        return (lead, slot // rows) + tail
+
+    spec = pl.BlockSpec((1, rows) + tuple(shape[2:]), index_map)
+    in_spec = spec if rows > 1 else pl.BlockSpec(memory_space=pl.ANY)
+    return spec, in_spec, rows
+
+
+def _scatter_kernel(slots_ref, new_ref, cache_ref, out_ref, *, rows):
+    i = pl.program_id(0)
+    _put(out_ref, cache_ref, new_ref[0, 0], slots_ref[i] % rows)
+
+
+def cache_update_pallas(cache: jnp.ndarray, new: jnp.ndarray,
+                        slots: jnp.ndarray,
+                        interpret: bool = False) -> jnp.ndarray:
+    """Scatter ``new[b, 0]`` into ``cache[b, slots[b]]`` for every row.
+
+    cache: (B, C, *rest)   new: (B, 1, *rest)   slots: (B,) int32 in
+    [0, C).  Returns the updated cache; the input buffer is aliased.
+    """
+    b = cache.shape[0]
+    lanes = (0,) * (cache.ndim - 2)
+    spec, in_spec, rows = _cache_spec(cache.shape,
+                                      lambda i, slots: (i, slots[i]))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, 1) + cache.shape[2:],
+                         lambda i, slots: (i, 0) + lanes),     # new row
+            in_spec,                                           # cache
+        ],
+        out_specs=spec,
+    )
+    return pl.pallas_call(
+        functools.partial(_scatter_kernel, rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        # index 2 counts the scalar-prefetch operand: (slots, new, cache)
+        input_output_aliases={2: 0},
+        interpret=interpret,
+        name="cache_update",
+    )(slots.astype(jnp.int32), new.astype(cache.dtype), cache)
+
+
+def _paged_route(pt, starts, valids, bi, ti, *, ps: int, nb: int):
+    """(physical page, row) of logical position ``starts[bi] + ti``;
+    masked rows (``ti >= valids[bi]``) go to scratch page 0, row 0."""
+    pos = jnp.minimum(starts[bi] + ti, nb * ps - 1)
+    ok = ti < valids[bi]
+    page = jnp.where(ok, pt[bi, pos // ps], 0)
+    row = jnp.where(ok, pos % ps, 0)
+    return page, row
+
+
+def _first_visit(pt, starts, valids, *, ps: int, nb: int, rows: int):
+    """Whether this grid step's aligned pool block differs from the
+    previous step's.  Pages are owned by one slot and a slot's positions
+    ascend, so the visits to one block are consecutive: the pipeline
+    keeps the out block resident across them, and only the first one
+    must read the block from the cache."""
+    bi, ti, nt = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    route = functools.partial(_paged_route, pt, starts, valids, ps=ps, nb=nb)
+    page, row = route(bi, ti)
+    prev_b = jnp.where(ti > 0, bi, bi - 1)
+    prev_page, prev_row = route(jnp.maximum(prev_b, 0),
+                                jnp.where(ti > 0, ti - 1, nt - 1))
+    return ((prev_b < 0) | (page != prev_page)
+            | (row // rows != prev_row // rows)), row % rows
+
+
+def _new_spec(shape):
+    """BlockSpec for the (B, T, *rest) rows a paged write takes in.  One
+    row per grid step where ``rest`` spans two dims; with one trailing
+    dim, T sits in the sublane dim and the block is a row's whole chunk
+    (revisited, so fetched once per batch row)."""
+    lanes = (0,) * (len(shape) - 2)
+    if len(shape) >= 4 or shape[1] == 1:
+        return pl.BlockSpec((1, 1) + tuple(shape[2:]),
+                            lambda bi, ti, *_: (bi, ti) + lanes)
+    return pl.BlockSpec(tuple((1,) + shape[1:]),
+                        lambda bi, ti, *_: (bi, 0) + lanes)
+
+
+def _new_row(new_ref):
+    """This grid step's row of the block :func:`_new_spec` selected.  A
+    whole-chunk block is reduced with a one-hot mask: a packed (bf16)
+    block cannot be indexed at an unaligned dynamic sublane, and adding
+    zeros in float32 returns the row exactly."""
+    if new_ref.shape[1] == 1:
+        return new_ref[0, 0]
+    blk = new_ref[0].astype(jnp.float32)
+    ids = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+    return jnp.sum(jnp.where(ids == pl.program_id(1), blk, 0.0), axis=0)
 
 
 def _paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref, pool_ref,
-                          out_ref):
-    # pool_ref is the aliased physical pool (never read): the alias
-    # keeps every row this program does not own; the out BlockSpec's
-    # index map already routed this program's row (or the scratch page,
-    # for masked rows) — see paged_cache_update_pallas.
-    del pt_ref, starts_ref, valids_ref, pool_ref
-    out_ref[...] = new_ref[...]
+                          out_ref, *, ps: int, nb: int, rows: int):
+    # The out BlockSpec's index map already routed this program's row
+    # (or the scratch page, for masked rows) — see paged_cache_update_pallas.
+    first, r = _first_visit(pt_ref, starts_ref, valids_ref, ps=ps, nb=nb,
+                            rows=rows)
+    _put(out_ref, pool_ref, _new_row(new_ref), r, first)
 
 
 def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
@@ -52,8 +189,8 @@ def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
     """Paged scatter: row ``t`` of ``new[b]`` lands at logical position
     ``starts[b] + t`` of row ``b``'s paged cache.
 
-    pool: (P, page_size, F) physical pages shared by all rows.
-    new: (B, T, F) rows to write.  page_table: (B, NB) int32 logical
+    pool: (P, page_size, *rest) physical pages shared by all rows.
+    new: (B, T, *rest) rows to write.  page_table: (B, NB) int32 logical
     block -> physical page.  starts: (B,) int32 first logical position.
     valids: (B,) int32 — rows ``t >= valids[b]`` are masked: the index
     map routes them to the scratch page 0 (whose content is undefined
@@ -63,71 +200,30 @@ def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
     valids == 1) and chunked prefill (T == chunk, per-row valid
     lengths).  Returns the updated pool; the input pool is aliased.
     """
-    p, ps, f = pool.shape
-    b, t, _ = new.shape
+    ps = pool.shape[1]
+    b, t = new.shape[:2]
     nb = page_table.shape[1]
-
-    def new_map(bi, ti, pt, starts, valids):
-        return (bi, ti, 0)
-
-    def out_map(bi, ti, pt, starts, valids):
-        # Page-table indirection in the index map: the scalar-prefetch
-        # page table turns (logical position) into (physical page, row).
-        # Masked rows go to scratch page 0 row 0 — revisits of that
-        # index collapse into at most one junk DMA per (b) sweep.
-        pos = jnp.minimum(starts[bi] + ti, nb * ps - 1)
-        ok = ti < valids[bi]
-        page = jnp.where(ok, pt[bi, pos // ps], 0)
-        row = jnp.where(ok, pos % ps, 0)
-        return (page, row, 0)
-
+    route = functools.partial(_paged_route, ps=ps, nb=nb)
+    spec, in_spec, rows = _cache_spec(
+        pool.shape,
+        lambda bi, ti, pt, starts, valids: route(pt, starts, valids, bi, ti))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, t),
-        in_specs=[
-            pl.BlockSpec((1, 1, f), new_map),                 # new row
-            pl.BlockSpec(memory_space=pl.ANY),                # pool
-        ],
-        out_specs=pl.BlockSpec((1, 1, f), out_map),
+        in_specs=[_new_spec(new.shape), in_spec],              # new, pool
+        out_specs=spec,
     )
     return pl.pallas_call(
-        _paged_scatter_kernel,
+        functools.partial(_paged_scatter_kernel, ps=ps, nb=nb, rows=rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         # index 4 counts the scalar-prefetch operands:
         # (page_table, starts, valids, new, pool)
         input_output_aliases={4: 0},
         interpret=interpret,
+        name="paged_cache_update",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       valids.astype(jnp.int32), new.astype(pool.dtype), pool)
-
-
-def cache_update_pallas(cache: jnp.ndarray, new: jnp.ndarray,
-                        slots: jnp.ndarray,
-                        interpret: bool = False) -> jnp.ndarray:
-    """Scatter ``new[b, 0]`` into ``cache[b, slots[b]]`` for every row.
-
-    cache: (B, C, F)   new: (B, 1, F)   slots: (B,) int32 in [0, C).
-    Returns the updated (B, C, F) cache; the input buffer is aliased.
-    """
-    b, _, f = cache.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1, f), lambda i, slots: (i, 0, 0)),  # new row
-            pl.BlockSpec(memory_space=pl.ANY),                    # cache
-        ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda i, slots: (i, slots[i], 0)),
-    )
-    return pl.pallas_call(
-        _scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-        # index 2 counts the scalar-prefetch operand: (slots, new, cache)
-        input_output_aliases={2: 0},
-        interpret=interpret,
-    )(slots.astype(jnp.int32), new.astype(cache.dtype), cache)
 
 
 # -- fused quantize + scatter (quantized KV caches) ---------------------------
@@ -135,23 +231,32 @@ def cache_update_pallas(cache: jnp.ndarray, new: jnp.ndarray,
 # The quantized cache stores low-bit codes plus one float32 absmax
 # scale per (token, head) row (kernels/quant.py).  These twins fuse the
 # quantization into the scatter: each program reads its full-precision
-# row, computes the per-head absmax scale in-register, and DMAs the
-# codes row and the scale row into their (aliased) caches — so a decode
+# row, computes the per-head absmax scale in-register, and writes the
+# codes row (a row block) and the scale row (an aligned block of the
+# (N, slots, H) scale array) into their aliased caches — so a decode
 # step's cache write streams the incoming row once, at full precision,
 # and everything it stores is already quantized.
 
-def _quant_scatter_kernel(slots_ref, new_ref, cache_ref, scales_ref,
-                          out_ref, s_out_ref, *, mode):
-    del slots_ref, cache_ref, scales_ref          # aliased, never read
+def _quantize_row(x, mode: str):
+    """(H, D) full-precision row -> (codes (H, D), scales (H,)), with
+    the op order of ``quant.quantize``."""
     qm = quant.qmax(mode)
-    x = new_ref[0, 0].astype(jnp.float32)         # (H, D)
-    amax = jnp.max(jnp.abs(x), axis=-1)           # (H,)
+    x = x.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(x), axis=-1)
     s = jnp.maximum(amax, quant.SCALE_EPS) * quant.qmax_inv(mode)
     y = x / s[:, None]
     if mode == "int8":
         y = jnp.round(y)
-    out_ref[0, 0] = jnp.clip(y, -qm, qm).astype(out_ref.dtype)
-    s_out_ref[0, 0] = s
+    return jnp.clip(y, -qm, qm), s
+
+
+def _quant_scatter_kernel(slots_ref, new_ref, cache_ref, scales_ref,
+                          out_ref, s_out_ref, *, mode, rows):
+    del cache_ref                                  # aliased, never read
+    i = pl.program_id(0)
+    codes, s = _quantize_row(new_ref[0, 0], mode)
+    _put(out_ref, None, codes, 0)
+    _put(s_out_ref, scales_ref, s, slots_ref[i] % rows)
 
 
 def quant_cache_update_pallas(cache: jnp.ndarray, scales: jnp.ndarray,
@@ -166,44 +271,40 @@ def quant_cache_update_pallas(cache: jnp.ndarray, scales: jnp.ndarray,
     Returns (cache, scales) updated; both input buffers are aliased.
     """
     b, _, h, d = cache.shape
+    index = lambda i, slots: (i, slots[i])
+    spec, _, _ = _cache_spec(cache.shape, index)
+    s_spec, s_in_spec, rows = _cache_spec(scales.shape, index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, 1, h, d), lambda i, slots: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),                # cache
-            pl.BlockSpec(memory_space=pl.ANY),                # scales
+            s_in_spec,                                        # scales
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, h, d),
-                         lambda i, slots: (i, slots[i], 0, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, slots: (i, slots[i], 0)),
-        ],
+        out_specs=[spec, s_spec],
     )
     return pl.pallas_call(
-        functools.partial(_quant_scatter_kernel, mode=mode),
+        functools.partial(_quant_scatter_kernel, mode=mode, rows=rows),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(cache.shape, cache.dtype),
                    jax.ShapeDtypeStruct(scales.shape, scales.dtype)],
         # operands: (slots, new, cache, scales)
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        name="quant_cache_update",
     )(slots.astype(jnp.int32), new, cache, scales)
 
 
 def _quant_paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref,
                                 pool_ref, spool_ref, out_ref, s_out_ref,
-                                *, mode):
-    del pt_ref, starts_ref, valids_ref, pool_ref, spool_ref
-    qm = quant.qmax(mode)
-    x = new_ref[0, 0].astype(jnp.float32)         # (H, D)
-    amax = jnp.max(jnp.abs(x), axis=-1)
-    s = jnp.maximum(amax, quant.SCALE_EPS) * quant.qmax_inv(mode)
-    y = x / s[:, None]
-    if mode == "int8":
-        y = jnp.round(y)
-    out_ref[0, 0] = jnp.clip(y, -qm, qm).astype(out_ref.dtype)
-    s_out_ref[0, 0] = s
+                                *, mode, ps: int, nb: int, rows: int):
+    del pool_ref                                   # aliased, never read
+    codes, s = _quantize_row(new_ref[0, 0], mode)
+    _put(out_ref, None, codes, 0)
+    first, r = _first_visit(pt_ref, starts_ref, valids_ref, ps=ps, nb=nb,
+                            rows=rows)
+    _put(s_out_ref, spool_ref, s, r, first)
 
 
 def quant_paged_cache_update_pallas(pool: jnp.ndarray, scales: jnp.ndarray,
@@ -223,48 +324,34 @@ def quant_paged_cache_update_pallas(pool: jnp.ndarray, scales: jnp.ndarray,
     new: (B, T, H, D)   page_table: (B, NB) int32   starts/valids: (B,).
     Returns (pool, scales) updated; both input buffers are aliased.
     """
-    p, ps, h, d = pool.shape
+    ps, h, d = pool.shape[1:]
     b, t = new.shape[:2]
     nb = page_table.shape[1]
-
-    def new_map(bi, ti, pt, starts, valids):
-        return (bi, ti, 0, 0)
-
-    def _route(bi, ti, pt, starts, valids):
-        pos = jnp.minimum(starts[bi] + ti, nb * ps - 1)
-        ok = ti < valids[bi]
-        page = jnp.where(ok, pt[bi, pos // ps], 0)
-        row = jnp.where(ok, pos % ps, 0)
-        return page, row
-
-    def out_map(bi, ti, pt, starts, valids):
-        page, row = _route(bi, ti, pt, starts, valids)
-        return (page, row, 0, 0)
-
-    def s_out_map(bi, ti, pt, starts, valids):
-        page, row = _route(bi, ti, pt, starts, valids)
-        return (page, row, 0)
-
+    route = functools.partial(_paged_route, ps=ps, nb=nb)
+    index = lambda bi, ti, pt, starts, valids: route(pt, starts, valids,
+                                                     bi, ti)
+    spec, _, _ = _cache_spec(pool.shape, index)
+    s_spec, s_in_spec, rows = _cache_spec(scales.shape, index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, t),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), new_map),              # new row
+            pl.BlockSpec((1, 1, h, d), lambda bi, ti, pt, starts, valids:
+                         (bi, ti, 0, 0)),                     # new row
             pl.BlockSpec(memory_space=pl.ANY),                # pool
-            pl.BlockSpec(memory_space=pl.ANY),                # scale pool
+            s_in_spec,                                        # scale pool
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, h, d), out_map),
-            pl.BlockSpec((1, 1, h), s_out_map),
-        ],
+        out_specs=[spec, s_spec],
     )
     return pl.pallas_call(
-        functools.partial(_quant_paged_scatter_kernel, mode=mode),
+        functools.partial(_quant_paged_scatter_kernel, mode=mode, ps=ps,
+                          nb=nb, rows=rows),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct(scales.shape, scales.dtype)],
         # operands: (page_table, starts, valids, new, pool, scales)
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="quant_paged_cache_update",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       valids.astype(jnp.int32), new, pool, scales)

@@ -1,9 +1,10 @@
 """Dispatching wrapper for the per-row cache scatter.
 
 ``cache_update`` accepts caches with arbitrary trailing dims —
-(B, C, KVH, hd) attention K/V, (B, C, R) MLA latents — flattens them to
-the kernel's (B, C, F) layout, and routes to the Pallas scatter on TPU
-or the ``vmap``'d ``dynamic_update_slice`` oracle elsewhere.
+(B, C, KVH, hd) attention K/V, (B, C, R) MLA latents — in their own
+layout (the kernel picks its block form from the rank; see
+``cache_update.py``), and routes to the Pallas scatter on TPU or the
+``vmap``'d ``dynamic_update_slice`` oracle elsewhere.
 
 ``impl`` — "auto" (Pallas iff the default backend is TPU), "pallas",
 "pallas_interpret" (CPU parity testing), or "lax".  The env var
@@ -44,11 +45,8 @@ def cache_update(cache: jnp.ndarray, new: jnp.ndarray, slots: jnp.ndarray,
         return cache_update_ref(cache, new, slots)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown cache_update impl {impl!r}")
-    b, c = cache.shape[:2]
-    flat = cache.reshape(b, c, -1)
-    out = cache_update_pallas(flat, new.astype(cache.dtype).reshape(b, 1, -1),
-                              slots, interpret=impl == "pallas_interpret")
-    return out.reshape(cache.shape)
+    return cache_update_pallas(cache, new, slots,
+                               interpret=impl == "pallas_interpret")
 
 
 def paged_cache_update(pool: jnp.ndarray, new: jnp.ndarray,
@@ -69,12 +67,8 @@ def paged_cache_update(pool: jnp.ndarray, new: jnp.ndarray,
         return paged_cache_update_ref(pool, new, page_table, starts, valids)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown cache_update impl {impl!r}")
-    p, ps = pool.shape[:2]
-    b, t = new.shape[:2]
-    out = paged_cache_update_pallas(
-        pool.reshape(p, ps, -1), new.astype(pool.dtype).reshape(b, t, -1),
-        page_table, starts, valids, interpret=impl == "pallas_interpret")
-    return out.reshape(pool.shape)
+    return paged_cache_update_pallas(pool, new, page_table, starts, valids,
+                                     interpret=impl == "pallas_interpret")
 
 
 # -- quantized writes (codes + per-row scales) --------------------------------

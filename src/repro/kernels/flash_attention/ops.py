@@ -18,9 +18,6 @@ from repro.kernels.flash_attention.flash_attention import \
     flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
-_INTERPRET_DEFAULT = jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _fa(q, k, v, causal, window, softcap, scale, block_q, block_k,
@@ -53,9 +50,12 @@ _fa.defvjp(_fa_fwd, _fa_bwd)
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     softcap=None, scale: float = 1.0, block_q: int = 256,
                     block_k: int = 256, interpret=None):
-    """q: (B, Sq, H, hd), k/v: (B, Skv, KVH, hd) -> (B, Sq, H, hd)."""
+    """q: (B, Sq, H, hd), k/v: (B, Skv, KVH, hd) -> (B, Sq, H, hd).
+
+    ``interpret=None`` compiles the kernel on a TPU backend and runs it
+    in interpret mode anywhere else, decided when called."""
     if interpret is None:
-        interpret = _INTERPRET_DEFAULT
+        interpret = jax.default_backend() != "tpu"
     b, sq, h, hd = q.shape
     _, skv, kvh, _ = k.shape
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, hd)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 TWO_PI = 2.0 * math.pi
 
 
@@ -69,7 +67,7 @@ def gridder_pallas(lm, uv, vis, block_v: int = 128,
         ],
         out_specs=pl.BlockSpec((1, p, 2), lambda i, k: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((s, p, 2), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lm, uv, vis)
@@ -106,7 +104,7 @@ def degridder_pallas(lm, uv, subgrids, block_v: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bv, 2), lambda i, k: (i, k, 0)),
         out_shape=jax.ShapeDtypeStruct((s, v, 2), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(lm, uv, subgrids)

@@ -16,11 +16,15 @@ is the admission hot path: a ``(T, G)``-packed query block attending to
   * the **chunk's own keys** — passed separately (they have not been
     scattered into the cache yet), causally masked in-kernel.
 
-Grid is (B, KVH, cache_steps + chunk_steps) with the kv sweep innermost
-(``arbitrary`` semantics); the fp32 (T, G, hdv) accumulator plus running
+Grid is (B, cache_steps + chunk_steps) with the kv sweep innermost
+(``arbitrary`` semantics).  As in flash-decode, one K/V block spans
+every kv head, ``(1, bk, KVH, hd)`` — the TPU tiling rule holds because
+its last two dims are the array's — and the kernel walks the heads
+in-register; the per-head fp32 (T, G, hdv) accumulators plus running
 row-max/row-sum live in VMEM scratch across both phases of the sweep —
 one continuous online softmax, so the result is a single attention over
-[prefix ++ chunk].
+[prefix ++ chunk].  Compiled calls need the cache and chunk block sizes
+to be multiples of 8 (or the whole axis), as in flash-decode.
 
 Ring caches (sliding-window layers): slot ``s`` holds position
 ``(offs-1) - ((offs-1-s) mod C)``.  Chunk queries trail the newest
@@ -47,9 +51,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.constants import DEFAULT_BLOCK_K, NEG_INF
+from repro.kernels.decode_attention.decode_attention import (
+    check_block, finish, init, scratch, unpack)
 from repro.kernels.prefill_attention.ref import pick_block_k
+
+
+def _fold_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+                valid, *, scale: float, softcap):
+    """Fold one (bk, KVH, hd) key block into every head's online-softmax
+    accumulator.  ``valid``: (T, 1, bk) — broadcast over the G axis of
+    the scores and shared by all heads.  ``ks_ref``/``vs_ref``: the
+    block's (bk, KVH) scales when it holds quantized codes."""
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32) * scale            # (T, G, hdq)
+        k_blk = k_ref[0, :, h, :]
+        v_blk = v_ref[0, :, h, :]
+        if ks_ref is not None:
+            k_blk = k_blk.astype(jnp.float32) * \
+                ks_ref[0, :, h:h + 1].astype(jnp.float32)
+            v_blk = v_blk.astype(jnp.float32) * \
+                vs_ref[0, :, h:h + 1].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k_blk.astype(jnp.float32), (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (T, G, bk)
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[h]                                      # (T, G, 1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v_blk.astype(jnp.float32), (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (T, G, hdv)
+        acc_ref[h] = alpha * acc_ref[h] + pv
+        m_ref[h] = m_new
+
+
+def _chunk_phase(q_ref, kx_ref, vx_ref, m_ref, l_ref, acc_ref, ki, *,
+                 cache_steps: int, bk_t: int, chunk: int, window, scale,
+                 softcap):
+    """Fold the chunk's own keys (causal; every block holds a key some
+    query attends, so none are skippable)."""
+    q_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
+    j_lo = (ki - cache_steps) * bk_t
+    cols = j_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk_t), 2)
+    diff = q_idx - cols                                        # (T, 1, bk_t)
+    valid = diff >= 0
+    if window is not None:
+        valid &= diff < window
+    _fold_heads(q_ref, kx_ref, vx_ref, None, None, m_ref, l_ref, acc_ref,
+                valid, scale=scale, softcap=softcap)
 
 
 def _prefill_kernel(offs_ref, q_ref, kx_ref, vx_ref, kc_ref, vc_ref, *refs,
@@ -57,47 +112,14 @@ def _prefill_kernel(offs_ref, q_ref, kx_ref, vx_ref, kc_ref, vc_ref, *refs,
                     bk_c: int, bk_t: int, cache_steps: int,
                     total_steps: int, cache_size: int, chunk: int,
                     quantized: bool = False):
-    # Quantized call sites append two float32 cache-scale operands —
-    # the ref list is (kcs, vcs, o, m, l, acc) or (o, m, l, acc).
-    if quantized:
-        kcs_ref, vcs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        o_ref, m_ref, l_ref, acc_ref = refs
-        kcs_ref = vcs_ref = None
+    kcs_ref, vcs_ref, o_ref, m_ref, l_ref, acc_ref = unpack(refs, quantized)
     bi = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     off = offs_ref[bi]
 
     @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-
-    def fold(k_blk, v_blk, valid):
-        """One online-softmax fold.  k_blk: (bk, hdq), v_blk: (bk, hdv),
-        valid: (T, 1, bk) — broadcast over the G axis of the scores."""
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (T, G, hdq)
-        s = jax.lax.dot_general(
-            q, k_blk.astype(jnp.float32), (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (T, G, bk)
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]                                # (T, G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_blk.astype(jnp.float32), (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (T, G, hdv)
-        acc_ref[...] = alpha * acc_ref[...] + pv
-        m_ref[...] = m_new
+    def _():
+        init(m_ref, l_ref, acc_ref)
 
     # -- phase 1: cache prefix.  Blocks whose first slot is at or past
     # the row's written prefix hold nothing attendable (full cache:
@@ -108,38 +130,26 @@ def _prefill_kernel(offs_ref, q_ref, kx_ref, vx_ref, kc_ref, vc_ref, *refs,
     def _cache_phase():
         k_lo = ki * bk_c
         cols = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk_c), 2)
-        q_pos = off + q_idx                                # (T, 1, 1)
+        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
         if ring:
             last = off - 1
             pos = last - jnp.mod(last - cols, cache_size)
             valid = (pos >= 0) & (q_pos - pos < window)
         else:
             valid = jnp.broadcast_to(cols < off, (chunk, 1, bk_c))
-        kb = kc_ref[0, :, 0, :]
-        vb = vc_ref[0, :, 0, :]
-        if quantized:
-            kb = kb.astype(jnp.float32) * \
-                kcs_ref[0, :, 0].astype(jnp.float32)[:, None]
-            vb = vb.astype(jnp.float32) * \
-                vcs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        fold(kb, vb, valid)
+        _fold_heads(q_ref, kc_ref, vc_ref, kcs_ref, vcs_ref, m_ref, l_ref,
+                    acc_ref, valid, scale=scale, softcap=softcap)
 
-    # -- phase 2: the chunk's own keys (causal; every block holds a key
-    # some query attends, so none are skippable).
+    # -- phase 2: the chunk's own keys.
     @pl.when(ki >= cache_steps)
-    def _chunk_phase():
-        j_lo = (ki - cache_steps) * bk_t
-        cols = j_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk_t), 2)
-        diff = q_idx - cols                                # (T, 1, bk_t)
-        valid = diff >= 0
-        if window is not None:
-            valid &= diff < window
-        fold(kx_ref[0, :, 0, :], vx_ref[0, :, 0, :], valid)
+    def _():
+        _chunk_phase(q_ref, kx_ref, vx_ref, m_ref, l_ref, acc_ref, ki,
+                     cache_steps=cache_steps, bk_t=bk_t, chunk=chunk,
+                     window=window, scale=scale, softcap=softcap)
 
     @pl.when(ki == total_steps - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def _():
+        finish(o_ref, l_ref, acc_ref)
 
 
 def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
@@ -147,43 +157,14 @@ def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
                           ps: int, bk_t: int, cache_steps: int,
                           total_steps: int, chunk: int,
                           quantized: bool = False):
-    if quantized:
-        kcs_ref, vcs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        o_ref, m_ref, l_ref, acc_ref = refs
-        kcs_ref = vcs_ref = None
+    kcs_ref, vcs_ref, o_ref, m_ref, l_ref, acc_ref = unpack(refs, quantized)
     bi = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     off = offs_ref[bi]
 
     @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-
-    def fold(k_blk, v_blk, valid):
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (T, G, hdq)
-        s = jax.lax.dot_general(
-            q, k_blk.astype(jnp.float32), (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (T, G, bk)
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]                                # (T, G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_blk.astype(jnp.float32), (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (T, G, hdv)
-        acc_ref[...] = alpha * acc_ref[...] + pv
-        m_ref[...] = m_new
+    def _():
+        init(m_ref, l_ref, acc_ref)
 
     # -- phase 1: the paged cache prefix.  One block == one physical
     # page; unwrapped layout (slot == position), so beyond-prefix pages
@@ -197,35 +178,24 @@ def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
     @pl.when(live)
     def _cache_phase():
         cols = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
-        q_pos = off + q_idx                                # (T, 1, 1)
+        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
         valid = jnp.broadcast_to(cols < off, (chunk, 1, ps))
         if window is not None:
             valid &= (q_pos - cols) < window
-        kb = kc_ref[0, :, 0, :]
-        vb = vc_ref[0, :, 0, :]
-        if quantized:
-            kb = kb.astype(jnp.float32) * \
-                kcs_ref[0, :, 0].astype(jnp.float32)[:, None]
-            vb = vb.astype(jnp.float32) * \
-                vcs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        fold(kb, vb, valid)
+        _fold_heads(q_ref, kc_ref, vc_ref, kcs_ref, vcs_ref, m_ref, l_ref,
+                    acc_ref, valid, scale=scale, softcap=softcap)
 
-    # -- phase 2: the chunk's own keys (causal; identical to the
-    # contiguous kernel — the chunk is not paged).
+    # -- phase 2: the chunk's own keys (identical to the contiguous
+    # kernel — the chunk is not paged).
     @pl.when(ki >= cache_steps)
-    def _chunk_phase():
-        j_lo = (ki - cache_steps) * bk_t
-        cols = j_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk_t), 2)
-        diff = q_idx - cols                                # (T, 1, bk_t)
-        valid = diff >= 0
-        if window is not None:
-            valid &= diff < window
-        fold(kx_ref[0, :, 0, :], vx_ref[0, :, 0, :], valid)
+    def _():
+        _chunk_phase(q_ref, kx_ref, vx_ref, m_ref, l_ref, acc_ref, ki,
+                     cache_steps=cache_steps, bk_t=bk_t, chunk=chunk,
+                     window=window, scale=scale, softcap=softcap)
 
     @pl.when(ki == total_steps - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def _():
+        finish(o_ref, l_ref, acc_ref)
 
 
 def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
@@ -237,18 +207,20 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     (B, T, KVH, *); physical pools (P, page_size, KVH, *) addressed
     through page_table (B, NB) int32; offs (B,) int32.  The cache-phase
     BlockSpec index maps read the page table from scalar-prefetch SMEM
-    (one block == one page) with the same clamp-to-elide-DMA trick as
-    the contiguous kernel.  Paged caches are unwrapped: sliding windows
-    arrive as the explicit ``window`` mask, never ``ring``.
-    ``k_scale``/``v_scale``: (P, page_size, KVH) float32 per-row scale
-    pools when the code pools are quantized (chunk k/v stay full
-    precision).  Returns (B, KVH, T, G, hdv) in q.dtype."""
+    (one block == one page, all kv heads) with the same clamp-to-elide-
+    DMA trick as the contiguous kernel.  Paged caches are unwrapped:
+    sliding windows arrive as the explicit ``window`` mask, never
+    ``ring``.  ``k_scale``/``v_scale``: (P, page_size, KVH) float32
+    per-row scale pools when the code pools are quantized (chunk k/v
+    stay full precision).  Returns (B, KVH, T, G, hdv) in q.dtype."""
     b, kvh, t, g, hdq = q.shape
     ps = k_pool.shape[1]
     nb = page_table.shape[1]
     c = nb * ps
     hdv = v_width if v_width is not None else v_pool.shape[-1]
     bk_t = pick_block_k(t, ps)       # match the paged ref twin's blocking
+    if not interpret:
+        check_block(bk_t, t, "paged prefill_attention chunk blocks")
     cache_steps = nb
     chunk_steps = t // bk_t
     total_steps = cache_steps + chunk_steps
@@ -256,8 +228,8 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     if quantized and v_scale is None:
         v_scale = k_scale
 
-    def q_map(bi, hi, ki, offs, pt):
-        return (bi, hi, 0, 0, 0)
+    def q_map(bi, ki, offs, pt):
+        return (bi, 0, 0, 0, 0)
 
     def _page(bi, ki, offs, pt):
         # Clamp to the row's needed page range, then go through the
@@ -271,28 +243,28 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
             j = jnp.maximum(j, jnp.minimum(first, last))
         return pt[bi, j]
 
-    def cache_map(bi, hi, ki, offs, pt):
-        return (_page(bi, ki, offs, pt), 0, hi, 0)
+    def cache_map(bi, ki, offs, pt):
+        return (_page(bi, ki, offs, pt), 0, 0, 0)
 
-    def scale_map(bi, hi, ki, offs, pt):
+    def scale_map(bi, ki, offs, pt):
         # Same physical page as the codes: scale DMAs elide together.
-        return (_page(bi, ki, offs, pt), 0, hi)
+        return (_page(bi, ki, offs, pt), 0, 0)
 
-    def chunk_map(bi, hi, ki, offs, pt):
+    def chunk_map(bi, ki, offs, pt):
         j = jnp.clip(ki - cache_steps, 0, chunk_steps - 1)
-        return (bi, j, hi, 0)
+        return (bi, j, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, t, g, hdq), q_map),
-        pl.BlockSpec((1, bk_t, 1, hdq), chunk_map),
-        pl.BlockSpec((1, bk_t, 1, hdv), chunk_map),
-        pl.BlockSpec((1, ps, 1, hdq), cache_map),
-        pl.BlockSpec((1, ps, 1, hdv), cache_map),
+        pl.BlockSpec((1, kvh, t, g, hdq), q_map),
+        pl.BlockSpec((1, bk_t, kvh, hdq), chunk_map),
+        pl.BlockSpec((1, bk_t, kvh, hdv), chunk_map),
+        pl.BlockSpec((1, ps, kvh, hdq), cache_map),
+        pl.BlockSpec((1, ps, kvh, hdv), cache_map),
     ]
     operands = [q, k_chunk, v_chunk, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                     pl.BlockSpec((1, ps, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, ps, kvh), scale_map),
+                     pl.BlockSpec((1, ps, kvh), scale_map)]
         operands += [k_scale, v_scale]
 
     kernel = functools.partial(
@@ -301,22 +273,19 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
         chunk=t, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, total_steps),
+        grid=(b, total_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, t, g, hdv), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t, g, 1), jnp.float32),     # m: running row max
-            pltpu.VMEM((t, g, 1), jnp.float32),     # l: running row sum
-            pltpu.VMEM((t, g, hdv), jnp.float32),   # acc
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t, g, hdv), q_map),
+        scratch_shapes=scratch((kvh, t, g), hdv),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t, g, hdv), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(offs.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
 
 
@@ -337,6 +306,9 @@ def prefill_attention_pallas(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,
     hdv = v_width if v_width is not None else v_cache.shape[-1]
     bk_c = pick_block_k(c, block_k)
     bk_t = pick_block_k(t, block_k)
+    if not interpret:
+        check_block(bk_c, c, "prefill_attention cache blocks")
+        check_block(bk_t, t, "prefill_attention chunk blocks")
     cache_steps = c // bk_c
     chunk_steps = t // bk_t
     total_steps = cache_steps + chunk_steps
@@ -344,38 +316,40 @@ def prefill_attention_pallas(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,
     if quantized and v_scale is None:
         v_scale = k_scale
 
-    def q_map(bi, hi, ki, offs):
-        return (bi, hi, 0, 0, 0)
+    def q_map(bi, ki, offs):
+        return (bi, 0, 0, 0, 0)
 
-    def cache_map(bi, hi, ki, offs):
+    def _block(bi, ki, offs):
         # Clamp beyond-prefix blocks (and the whole chunk phase) to the
         # row's last needed cache block: a revisited block index elides
         # the HBM->VMEM copy entirely.
         last = jnp.minimum(jnp.maximum(offs[bi] - 1, 0), c - 1) // bk_c
-        return (bi, jnp.minimum(ki, last), hi, 0)
+        return jnp.minimum(ki, last)
 
-    def scale_map(bi, hi, ki, offs):
+    def cache_map(bi, ki, offs):
+        return (bi, _block(bi, ki, offs), 0, 0)
+
+    def scale_map(bi, ki, offs):
         # Code block and scale block share the clamp: both DMAs elide.
-        last = jnp.minimum(jnp.maximum(offs[bi] - 1, 0), c - 1) // bk_c
-        return (bi, jnp.minimum(ki, last), hi)
+        return (bi, _block(bi, ki, offs), 0)
 
-    def chunk_map(bi, hi, ki, offs):
+    def chunk_map(bi, ki, offs):
         # Parked at block 0 during the cache phase (no copy after the
         # first revisit), then walks the chunk.
         j = jnp.clip(ki - cache_steps, 0, chunk_steps - 1)
-        return (bi, j, hi, 0)
+        return (bi, j, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, t, g, hdq), q_map),
-        pl.BlockSpec((1, bk_t, 1, hdq), chunk_map),
-        pl.BlockSpec((1, bk_t, 1, hdv), chunk_map),
-        pl.BlockSpec((1, bk_c, 1, hdq), cache_map),
-        pl.BlockSpec((1, bk_c, 1, hdv), cache_map),
+        pl.BlockSpec((1, kvh, t, g, hdq), q_map),
+        pl.BlockSpec((1, bk_t, kvh, hdq), chunk_map),
+        pl.BlockSpec((1, bk_t, kvh, hdv), chunk_map),
+        pl.BlockSpec((1, bk_c, kvh, hdq), cache_map),
+        pl.BlockSpec((1, bk_c, kvh, hdv), cache_map),
     ]
     operands = [q, k_chunk, v_chunk, k_cache, v_cache]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bk_c, 1), scale_map),
-                     pl.BlockSpec((1, bk_c, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, bk_c, kvh), scale_map),
+                     pl.BlockSpec((1, bk_c, kvh), scale_map)]
         operands += [k_scale, v_scale]
 
     kernel = functools.partial(
@@ -384,20 +358,17 @@ def prefill_attention_pallas(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,
         total_steps=total_steps, cache_size=c, chunk=t, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, total_steps),
+        grid=(b, total_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, t, g, hdv), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t, g, 1), jnp.float32),     # m: running row max
-            pltpu.VMEM((t, g, 1), jnp.float32),     # l: running row sum
-            pltpu.VMEM((t, g, hdv), jnp.float32),   # acc
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t, g, hdv), q_map),
+        scratch_shapes=scratch((kvh, t, g), hdv),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t, g, hdv), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="prefill_attention",
     )(offs.astype(jnp.int32), *operands)

@@ -23,6 +23,7 @@ import repro.core as pmt
 from repro import configs
 from repro.core.backends.dummy import DummySensor
 from repro.core.supervisor import SensorSupervisor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_mod
 from repro.serve.engine import Request, ServeEngine, stall_p95
 from repro.serve.governor import PowerGovernor
@@ -152,6 +153,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=args.reduced)
     params, _ = model_mod.init_params(jax.random.PRNGKey(args.seed), cfg)
     # One shared session: the aggregate batch region and every request's

@@ -27,6 +27,7 @@ from repro import configs
 from repro.checkpoint.manager import (CheckpointManager, CheckpointMeta,
                                       latest_step, restore)
 from repro.data.pipeline import DataConfig, SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import base_rules, make_production_mesh, \
     make_smoke_mesh
 from repro.optim.optimizers import OptimizerConfig
@@ -60,6 +61,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=args.reduced)
     ocfg = OptimizerConfig(name=cfg.optimizer, lr=args.lr,
                            warmup_steps=min(20, args.steps // 5 + 1),

@@ -60,10 +60,6 @@ class CellCosts:
 
 def costs_from_compiled(compiled) -> CellCosts:
     ca = compiled.cost_analysis() or {}
-    # Older jax returns a one-element list of dicts (per device kind);
-    # newer jax returns the dict directly.  Normalize to the dict.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     text = compiled.as_text()
     stats = collective_bytes(text)
     return CellCosts(

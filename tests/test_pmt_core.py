@@ -311,6 +311,28 @@ def test_tpu_sensor_power_cap():
     assert dyn == pytest.approx(200.0 - 60.0)
 
 
+@pytest.mark.parametrize("kind,expect", [("TPU v5 lite", "tpu-v5e"),
+                                         ("TPU v9 imaginary", None)])
+def test_tpu_sensor_peaks_by_device_kind(monkeypatch, kind, expect):
+    """On a TPU backend the modelled sensor takes its peaks from the
+    device kind; an unknown kind raises instead of modelling v5e."""
+    import jax
+
+    class _Dev:
+        device_kind = kind
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    if expect is None:
+        with pytest.raises(ValueError, match="no HardwareSpec"):
+            pmt.create("tpu")
+    else:
+        assert pmt.create("tpu").model.hw.name == expect
+    # an explicit model is used as given, whatever the device
+    m = pmt.EnergyModel()
+    assert pmt.create("tpu", model=m).model is m
+
+
 @given(flops=st.floats(0, 1e18), hbm=st.floats(0, 1e15),
        ici=st.floats(0, 1e15), secs=st.floats(1e-3, 1e3))
 @settings(max_examples=50, deadline=None)
