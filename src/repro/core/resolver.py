@@ -36,6 +36,7 @@ that only export therefore never wait.
 from __future__ import annotations
 
 import threading
+import time
 import warnings
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
@@ -309,6 +310,11 @@ class SpanResolver(threading.Thread):
         self._poll_s = poll_s
         self.wake = threading.Event()
         self._stop_evt = threading.Event()
+        # Host cost of background resolution: passes that resolved at
+        # least one span, and the seconds spent in every pass.  Written
+        # by this thread alone.
+        self.batches = 0
+        self.busy_s = 0.0
 
     def stop(self, join: bool = True, timeout: float = 5.0) -> None:
         self._stop_evt.set()
@@ -319,6 +325,7 @@ class SpanResolver(threading.Thread):
     def run(self) -> None:
         session = self._session
         while True:
+            t_in = time.perf_counter()
             try:
                 claimed, deferred = session._drain_ready(force=False)
             except Exception as exc:  # pragma: no cover - backend broke
@@ -328,6 +335,9 @@ class SpanResolver(threading.Thread):
                 warnings.warn(f"pmt resolver: background resolve failed "
                               f"({exc!r}); retrying")
                 claimed, deferred = 0, 1
+            self.busy_s += time.perf_counter() - t_in
+            if claimed:
+                self.batches += 1
             if self._stop_evt.is_set():
                 return
             if claimed or deferred:

@@ -230,6 +230,10 @@ class RingSampler(_PeriodicThread):
         self._gaps = collections.deque(maxlen=256)   # closed (t0, t1)
         self._gap_open_ts: Optional[float] = None
         self._in_error_streak = False
+        # The sampler's own host cost: background ticks taken and the
+        # seconds spent in them.  Written by the sampling thread alone.
+        self.ticks = 0
+        self.tick_s = 0.0
 
     @property
     def sensor(self) -> Sensor:
@@ -241,14 +245,17 @@ class RingSampler(_PeriodicThread):
 
     # -- writer side -------------------------------------------------------
     def _tick(self) -> None:
+        t_in = time.perf_counter()
         with self._write_mutex:
             try:
                 t, j, w = self._sensor.read_raw()
             except Exception as e:   # noqa: BLE001 — any backend fault
                 self._note_read_failure(e)
-                return
-            self._note_read_success(t)
-            self._publish(t, j, w)
+            else:
+                self._note_read_success(t)
+                self._publish(t, j, w)
+        self.ticks += 1
+        self.tick_s += time.perf_counter() - t_in
 
     def _note_read_failure(self, e: Exception) -> None:
         """Record one failed read (caller holds ``_write_mutex``)."""

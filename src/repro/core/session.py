@@ -72,7 +72,9 @@ import bisect
 import collections
 import itertools
 import threading
+import time
 import warnings
+import weakref
 from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
                     Tuple, Union)
 
@@ -341,6 +343,22 @@ class RegionHandle:
         return self.measurements[0]
 
 
+class _CostCell:
+    """Lives in a thread's storage beside its region counters; its
+    finalizer (see ``Session._thread_cost``) runs when the thread ends."""
+
+    __slots__ = ("__weakref__",)
+
+
+def _fold_cost(costs: Dict[int, List[float]], done: List[float],
+               lock: threading.Lock, cost: List[float]) -> None:
+    """Move an ended thread's ``cost`` from ``costs`` into ``done``."""
+    with lock:
+        costs.pop(id(cost), None)
+        for i, v in enumerate(cost):
+            done[i] += v
+
+
 class Session:
     """Shared-sampler measurement facade (see module docstring).
 
@@ -395,6 +413,13 @@ class Session:
         self._resolver: Optional[resolver_mod.SpanResolver] = None
         self._stats = {"resolved": 0, "evicted": 0, "degraded": 0,
                        "dropped": 0, "resolve_errors": 0}
+        # The hot path's own cost, kept per calling thread so that no
+        # lock is taken and no update is lost: [opens, closes, seconds
+        # inside both], one list per live thread that opened a region;
+        # a thread's list is folded into _costs_done when it ends.
+        self._costs: Dict[int, List[float]] = {}
+        self._costs_done: List[float] = [0, 0, 0.0]
+        self._cost_lock = threading.Lock()
         self._tls = threading.local()
         self._anon = itertools.count(1)
         self._closed = False
@@ -519,6 +544,7 @@ class Session:
     def _open_span(self, label: Optional[str], flops: Optional[float],
                    tokens: Optional[int], on_resolved,
                    nested: bool = True) -> _Span:
+        t_in = time.perf_counter()
         if self._closed:
             raise SensorError("session is closed")
         open3, pairs = self._hot_snapshot
@@ -546,11 +572,15 @@ class Session:
                      pins, on_resolved, nested=nested)
         if nested:
             stack.append(label)
+        cost = self._thread_cost()
+        cost[0] += 1
+        cost[2] += time.perf_counter() - t_in
         return span
 
     def _close_span(self, span: Optional[_Span]) -> None:
         if span is None:
             return
+        t_in = time.perf_counter()
         pairs = self._hot_snapshot[1]
         if pairs is span.snap:       # common case: backend set unchanged
             span.t1 = {k: clk() for k, clk in pairs}
@@ -581,6 +611,25 @@ class Session:
         res = self._resolver
         if res is not None and not res.wake.is_set():
             res.wake.set()
+        cost = self._thread_cost()
+        cost[1] += 1
+        cost[2] += time.perf_counter() - t_in
+
+    def _thread_cost(self) -> List[float]:
+        """The calling thread's ``[opens, closes, seconds]`` counters.
+
+        They live in the thread-local storage beside a ``_CostCell``
+        whose finalizer folds them into the session's total when the
+        thread ends, so short-lived threads leave nothing behind."""
+        cost = getattr(self._tls, "cost", None)
+        if cost is None:
+            cost = self._tls.cost = [0, 0, 0.0]
+            cell = self._tls.cost_cell = _CostCell()
+            with self._cost_lock:
+                self._costs[id(cost)] = cost
+            weakref.finalize(cell, _fold_cost, self._costs,
+                             self._costs_done, self._cost_lock, cost)
+        return cost
 
     def _unpin_span(self, span: _Span) -> None:
         for sampler, tok in span.pins.values():
@@ -731,18 +780,45 @@ class Session:
         self._drain_emissions()
         return out
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
         """Resolution counters: ``resolved``, ``evicted`` (spans flagged
         ``window_evicted``), ``degraded`` (spans that straddled a sensor
         coverage gap), ``dropped`` (fell off the bounded queue — handles
         still resolve on access), ``resolve_errors``, and ``pending``
-        (closed spans not yet resolved)."""
+        (closed spans not yet resolved).
+
+        The measurement plane's own host time, each count beside the
+        seconds it took: ``region_opens``, ``region_closes`` and
+        ``region_s`` (the calling threads inside region entry and exit),
+        ``sampler_ticks`` and ``sampler_s`` (the background ticks of the
+        samplers this session leases, summed over its live leases; a
+        sampler shared with another session counts whole), and
+        ``resolver_batches`` and ``resolver_s`` (background passes that
+        resolved spans, and the seconds of every pass); and ``t_s``, the
+        ``time.perf_counter()`` reading they were taken at, so that the
+        change in seconds between two readings is divided by the time
+        between them."""
         with self._resolve_lock:
             pending = len(self._queue) + sum(
                 1 for s in self._waiting
                 if s.resolved is None and s.error is None)
-            out = dict(self._stats)
+            out: Dict[str, Any] = dict(self._stats)
         out["pending"] = pending
+        with self._cost_lock:
+            costs = [list(c) for c in self._costs.values()]
+            costs.append(list(self._costs_done))
+        samplers = [l.sampler for l in self._lease_snapshot]
+        samplers = [sp for sp in samplers if sp is not None]
+        res = self._resolver
+        out.update(
+            t_s=time.perf_counter(),
+            region_opens=sum(c[0] for c in costs),
+            region_closes=sum(c[1] for c in costs),
+            region_s=sum(c[2] for c in costs),
+            sampler_ticks=sum(getattr(sp, "ticks", 0) for sp in samplers),
+            sampler_s=sum(getattr(sp, "tick_s", 0.0) for sp in samplers),
+            resolver_batches=res.batches if res is not None else 0,
+            resolver_s=res.busy_s if res is not None else 0.0)
         return out
 
     def health(self) -> Dict[str, Any]:

@@ -63,6 +63,32 @@ token count is the *actually generated* total.  Passing a
 ``PowerMonitor`` routes the same spans through
 ``measure_step``/``measure_request(..., phase=...)`` accounting
 instead (``per_request_energy`` then carries the J split).
+``stats()["measurement"]`` (with a session) is the plane's own host
+cost: ``Session.stats()``'s region, sampler and resolver counters.
+
+Profiler annotations — for operators reading a ``jax.profiler`` trace
+(always on; about a microsecond each with the profiler off).  Every
+request span above, with or without a session or monitor, has a
+``TraceAnnotation`` twin of the same path (``serve/req<N>``,
+``serve/req<N>/prefill``, ``serve/req<N>/decode``), opened and closed
+with it, so each measured span finds its place on the device timeline
+by path.  The paged scheduler loop runs each part of an iteration under
+one phase annotation; the phases tile the loop body:
+
+  * ``engine/wait`` — asleep until an arrival or the end of a backoff;
+  * ``engine/admit`` — drain check, deadline sweep, gauges, governor
+    shed, admission (radix match, page reservation, span opening);
+  * ``engine/prefill`` — build and dispatch one batched chunk, then
+    complete and activate the rows it finished; ``engine/prefill/fetch``
+    inside it is the host waiting on the chunk's tokens;
+  * ``engine/decode`` — mask the page table and dispatch the burst of
+    steps; ``engine/decode/fetch`` inside it waits on the burst's
+    tokens;
+  * ``engine/retire`` — extend, quarantine, retire and radix-insert the
+    burst's rows.
+
+The step programs are named ``serve_decode``, ``serve_prefill_chunk``
+and ``serve_prefill`` (``jit_serve_decode`` in a trace).
 
 Known semantic caveat: MoE layers route with cross-batch capacity
 limits, so under continuous batching a request's tokens can be dropped
@@ -284,6 +310,49 @@ class Request:
     _swap_pages: int = 0
 
 
+class _Traced:
+    """A measurement context together with a profiler annotation of the
+    same path, opened and closed at the same points: the annotation
+    places the span on the device trace's clock."""
+
+    __slots__ = ("_ctx", "_ann")
+
+    def __init__(self, path: str, ctx):
+        self._ctx = ctx
+        self._ann = jax.profiler.TraceAnnotation(path)
+
+    def __enter__(self):
+        out = self._ctx.__enter__()
+        self._ann.__enter__()
+        return out
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return self._ctx.__exit__(*exc)
+
+
+class _Phases:
+    """The scheduler loop's phase as a profiler annotation.  Each call
+    closes the phase before and opens the next, so the phases tile the
+    loop body; ``end()`` closes the last."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self):
+        self._ann = None
+
+    def __call__(self, name: str) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = jax.profiler.TraceAnnotation(name)
+        self._ann.__enter__()
+
+    def end(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
 @dataclasses.dataclass
 class _Prefill:
     """An admission mid-chunked-prefill: its slot is reserved, its
@@ -495,6 +564,10 @@ class ServeEngine:
         self._batch_count = 0       # aggregate regions (waves or batches)
         self._request_count = 0
         self.stall_events: List[float] = []
+        # Decode bursts' occupancy: steps dispatched, and rows decoding
+        # times steps (their ratio over ``batch`` is the batch's fill).
+        self.decode_steps = 0
+        self.decode_row_steps = 0
         self._timeouts = 0          # requests retired past their deadline
         # rid -> tenant for every admitted request (telemetry's
         # /requests?tenant= filter reads this via attach_engine).
@@ -608,12 +681,17 @@ class ServeEngine:
                 donate_argnums=1)
 
     def _counted(self, name: str, fn):
+        """``fn`` counted in ``compile_counts[name]`` at each trace, and
+        named ``serve_<name>``: the name its compiled program carries in
+        a profile (``jit_serve_decode``), so the step programs are told
+        apart by name and not only by the kernels they run."""
         counts = self.compile_counts
 
         def wrapper(*args, **kwargs):
             counts[name] += 1       # runs at trace time == once per compile
             return fn(*args, **kwargs)
 
+        wrapper.__name__ = wrapper.__qualname__ = f"serve_{name}"
         return wrapper
 
     def _next_key(self):
@@ -744,13 +822,15 @@ class ServeEngine:
 
     def _request_ctx(self, rid: int, tokens: int,
                      phase: Optional[str] = None):
+        label = f"serve/req{rid}" + (f"/{phase}" if phase else "")
         if self.monitor is not None:
-            return self.monitor.measure_request(rid, tokens=tokens,
-                                                blocking=False, phase=phase)
-        if self.session is not None:
-            label = f"serve/req{rid}" + (f"/{phase}" if phase else "")
-            return self.session.region(label, tokens=tokens, nested=False)
-        return contextlib.nullcontext()
+            ctx = self.monitor.measure_request(rid, tokens=tokens,
+                                               blocking=False, phase=phase)
+        elif self.session is not None:
+            ctx = self.session.region(label, tokens=tokens, nested=False)
+        else:
+            ctx = contextlib.nullcontext()
+        return _Traced(label, ctx)
 
     # -- public API ----------------------------------------------------------
     def generate(self, requests: List[Request]) -> List[Request]:
@@ -822,7 +902,11 @@ class ServeEngine:
             "stall_p95_s": stall_p95(self.stall_events),
             "requests_timed_out": self._timeouts,
             "compile_counts": dict(self.compile_counts),
+            "decode_steps": self.decode_steps,
+            "decode_row_steps": self.decode_row_steps,
         }
+        if self.session is not None:
+            s["measurement"] = self.session.stats()
         cache_s: Dict[str, Any] = {
             "cache_dtype": (self.cfg.kv_quant
                             if self.cfg.kv_quant is not None
@@ -1193,6 +1277,8 @@ class ServeEngine:
                             self._next_key())
                         outs.append(tok_dev)
                         pos_dev = pos_dev + 1
+                    self.decode_steps += steps
+                    self.decode_row_steps += steps * len(live)
                     gen = np.asarray(jnp.concatenate(outs, axis=1))
                     # np read blocked: every token in the chunk is
                     # computed, so spans closed below are correctly
@@ -1608,10 +1694,14 @@ class ServeEngine:
             self.pending_prefill_chunks = sum(
                 math.ceil((st.plen - st.offset) / chunk) for st in prefills)
 
+        # Every part of a loop iteration runs under one phase annotation
+        # (see the module docstring's phase vocabulary).
+        phase = _Phases()
         with self._measure_ctx(agg_id, tokens=total_tokens):
             try:
                 while waiting or prefills \
                         or any(r is not None for r in active):
+                    phase("engine/admit")
                     if self._drain_requested:
                         self._drain_requested = False
                         drain_checkpoint()
@@ -1634,7 +1724,9 @@ class ServeEngine:
                         nxt = min(w._retry_at for w in waiting)
                         now_m = time.monotonic()
                         if nxt > now_m:
+                            phase("engine/wait")
                             time.sleep(min(nxt - now_m, 0.05))
+                            phase("engine/admit")
                     # Admission: governor gate (now fed the pool's free
                     # fraction as a pressure signal) + tenant pick, then
                     # page reservation.  A pool too drained to cover the
@@ -1734,6 +1826,7 @@ class ServeEngine:
                     update_gauges()
 
                     if prefills:
+                        phase("engine/prefill")
                         decode_live = any(a is not None for a in active)
                         budget = 1
                         if gov is not None:
@@ -1765,8 +1858,10 @@ class ServeEngine:
                                     jnp.asarray(offs), jnp.asarray(last),
                                     jnp.asarray(self._page_table),
                                     self._next_key())
-                            tok = np.asarray(tok)   # fence the dispatch
-                            okp = np.asarray(okp)
+                            with jax.profiler.TraceAnnotation(
+                                    "engine/prefill/fetch"):
+                                tok = np.asarray(tok)   # fence the dispatch
+                                okp = np.asarray(okp)
                             dt = time.perf_counter() - t0
                             note_watchdog(dt, 1, "prefill")
                             if decode_live:
@@ -1788,6 +1883,7 @@ class ServeEngine:
                                              tok[st.slot], st.plen)
                         update_gauges()
 
+                    phase("engine/decode")
                     live = [j for j in range(b) if active[j] is not None]
                     if not live:
                         continue
@@ -1821,10 +1917,14 @@ class ServeEngine:
                         outs.append(tok_dev)
                         oks.append(ok_dev)
                         pos_dev = pos_dev + 1
-                    gen = np.asarray(jnp.concatenate(outs, axis=1))
-                    okm = np.asarray(jnp.stack(oks, axis=1))
+                    self.decode_steps += steps
+                    self.decode_row_steps += steps * len(live)
+                    with jax.profiler.TraceAnnotation("engine/decode/fetch"):
+                        gen = np.asarray(jnp.concatenate(outs, axis=1))
+                        okm = np.asarray(jnp.stack(oks, axis=1))
                     note_watchdog(time.perf_counter() - t0d, steps,
                                   "decode")
+                    phase("engine/retire")
                     for j in live:
                         r = active[j]
                         row_ok = okm[j]
@@ -1864,6 +1964,7 @@ class ServeEngine:
                     dec_ctxs[j] = None
                     close_ctx(req_ctxs[j])
                     req_ctxs[j] = None
+                phase.end()
         return requests
 
     # -- synchronized waves (baseline) ---------------------------------------
