@@ -24,6 +24,13 @@ a tile or span the whole dim:
     the slot axis is the sublane dim, so the block covers ``R`` slots
     (8, or the whole axis when 8 does not divide it), is read in
     through the aliased input, and only the target row is replaced.
+
+The paged writes take the whole stacked pool ``(L, P, page_size,
+*rest)`` of a layer stack and the layer to write.  The stack is seen as
+one pool of ``L * P`` pages (a free reshape), and the layer rides in
+through scalar prefetch into the index maps, which offset the page by
+``layer * P``: the whole stack is aliased from input to output, and a
+write touches only its new rows, never a layer-sized slice of the pool.
 """
 from __future__ import annotations
 
@@ -173,8 +180,9 @@ def _new_row(new_ref):
     return jnp.sum(jnp.where(ids == pl.program_id(1), blk, 0.0), axis=0)
 
 
-def _paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref, pool_ref,
-                          out_ref, *, ps: int, nb: int, rows: int):
+def _paged_scatter_kernel(pt_ref, starts_ref, valids_ref, layer_ref, new_ref,
+                          pool_ref, out_ref, *, ps: int, nb: int, rows: int):
+    del layer_ref                                  # read by the index maps
     # The out BlockSpec's index map already routed this program's row
     # (or the scratch page, for masked rows) — see paged_cache_update_pallas.
     first, r = _first_visit(pt_ref, starts_ref, valids_ref, ps=ps, nb=nb,
@@ -182,14 +190,36 @@ def _paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref, pool_ref,
     _put(out_ref, pool_ref, _new_row(new_ref), r, first)
 
 
-def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
+def _flat(pool: jnp.ndarray) -> jnp.ndarray:
+    """A stacked (L, P, page_size, *rest) pool as one (L * P, ...) pool."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
+def _paged_pool_spec(shape, *, nb: int):
+    """Out spec, aliased-input spec and block rows for one page row of
+    the stacked pool ``shape`` = (L, P, page_size, *rest), passed in as
+    :func:`_flat` of it and routed by the index maps of a paged write:
+    scalar prefetch is (page_table, starts, valids, layer), and the
+    layer offsets the page by ``layer * P``."""
+    n, p, ps = shape[:3]
+    route = functools.partial(_paged_route, ps=ps, nb=nb)
+
+    def index(bi, ti, pt, starts, valids, layer):
+        page, row = route(pt, starts, valids, bi, ti)
+        return layer[0] * p + page, row
+
+    return _cache_spec((n * p,) + tuple(shape[2:]), index)
+
+
+def paged_cache_update_pallas(pool: jnp.ndarray, layer, new: jnp.ndarray,
                               page_table: jnp.ndarray, starts: jnp.ndarray,
                               valids: jnp.ndarray,
                               interpret: bool = False) -> jnp.ndarray:
     """Paged scatter: row ``t`` of ``new[b]`` lands at logical position
-    ``starts[b] + t`` of row ``b``'s paged cache.
+    ``starts[b] + t`` of row ``b``'s paged cache in layer ``layer``.
 
-    pool: (P, page_size, *rest) physical pages shared by all rows.
+    pool: (L, P, page_size, *rest) the stack's physical pages, shared
+    by all rows.  layer: int32 scalar (may be traced) in [0, L).
     new: (B, T, *rest) rows to write.  page_table: (B, NB) int32 logical
     block -> physical page.  starts: (B,) int32 first logical position.
     valids: (B,) int32 — rows ``t >= valids[b]`` are masked: the index
@@ -198,17 +228,15 @@ def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
 
     The same kernel covers both paged write paths: decode (T == 1,
     valids == 1) and chunked prefill (T == chunk, per-row valid
-    lengths).  Returns the updated pool; the input pool is aliased.
+    lengths).  Returns the updated pool; the whole stacked input pool is
+    aliased, and only the written rows change.
     """
-    ps = pool.shape[1]
+    ps = pool.shape[2]
     b, t = new.shape[:2]
     nb = page_table.shape[1]
-    route = functools.partial(_paged_route, ps=ps, nb=nb)
-    spec, in_spec, rows = _cache_spec(
-        pool.shape,
-        lambda bi, ti, pt, starts, valids: route(pt, starts, valids, bi, ti))
+    spec, in_spec, rows = _paged_pool_spec(pool.shape, nb=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, t),
         in_specs=[_new_spec(new.shape), in_spec],              # new, pool
         out_specs=spec,
@@ -216,14 +244,15 @@ def paged_cache_update_pallas(pool: jnp.ndarray, new: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_paged_scatter_kernel, ps=ps, nb=nb, rows=rows),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        # index 4 counts the scalar-prefetch operands:
-        # (page_table, starts, valids, new, pool)
-        input_output_aliases={4: 0},
+        out_shape=jax.ShapeDtypeStruct(_flat(pool).shape, pool.dtype),
+        # index 5 counts the scalar-prefetch operands:
+        # (page_table, starts, valids, layer, new, pool)
+        input_output_aliases={5: 0},
         interpret=interpret,
         name="paged_cache_update",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      valids.astype(jnp.int32), new.astype(pool.dtype), pool)
+      valids.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      new.astype(pool.dtype), _flat(pool)).reshape(pool.shape)
 
 
 # -- fused quantize + scatter (quantized KV caches) ---------------------------
@@ -296,10 +325,11 @@ def quant_cache_update_pallas(cache: jnp.ndarray, scales: jnp.ndarray,
     )(slots.astype(jnp.int32), new, cache, scales)
 
 
-def _quant_paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref,
-                                pool_ref, spool_ref, out_ref, s_out_ref,
-                                *, mode, ps: int, nb: int, rows: int):
-    del pool_ref                                   # aliased, never read
+def _quant_paged_scatter_kernel(pt_ref, starts_ref, valids_ref, layer_ref,
+                                new_ref, pool_ref, spool_ref, out_ref,
+                                s_out_ref, *, mode, ps: int, nb: int,
+                                rows: int):
+    del layer_ref, pool_ref         # read by the index maps; never read
     codes, s = _quantize_row(new_ref[0, 0], mode)
     _put(out_ref, None, codes, 0)
     first, r = _first_visit(pt_ref, starts_ref, valids_ref, ps=ps, nb=nb,
@@ -308,7 +338,7 @@ def _quant_paged_scatter_kernel(pt_ref, starts_ref, valids_ref, new_ref,
 
 
 def quant_paged_cache_update_pallas(pool: jnp.ndarray, scales: jnp.ndarray,
-                                    new: jnp.ndarray,
+                                    layer, new: jnp.ndarray,
                                     page_table: jnp.ndarray,
                                     starts: jnp.ndarray, valids: jnp.ndarray,
                                     mode: str,
@@ -320,38 +350,39 @@ def quant_paged_cache_update_pallas(pool: jnp.ndarray, scales: jnp.ndarray,
     pool pages alongside its code pool, so the per-row scales are
     page-granular and travel with the page through prefix sharing).
 
-    pool: (P, page_size, H, D) codes   scales: (P, page_size, H) f32
+    pool: (L, P, page_size, H, D) codes
+    scales: (L, P, page_size, H) f32   layer: int32 scalar in [0, L)
     new: (B, T, H, D)   page_table: (B, NB) int32   starts/valids: (B,).
-    Returns (pool, scales) updated; both input buffers are aliased.
+    Returns (pool, scales) updated; both whole stacked input buffers
+    are aliased.
     """
-    ps, h, d = pool.shape[1:]
+    ps, h, d = pool.shape[2:]
     b, t = new.shape[:2]
     nb = page_table.shape[1]
-    route = functools.partial(_paged_route, ps=ps, nb=nb)
-    index = lambda bi, ti, pt, starts, valids: route(pt, starts, valids,
-                                                     bi, ti)
-    spec, _, _ = _cache_spec(pool.shape, index)
-    s_spec, s_in_spec, rows = _cache_spec(scales.shape, index)
+    spec, _, _ = _paged_pool_spec(pool.shape, nb=nb)
+    s_spec, s_in_spec, rows = _paged_pool_spec(scales.shape, nb=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, t),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), lambda bi, ti, pt, starts, valids:
+            pl.BlockSpec((1, 1, h, d), lambda bi, ti, *_:
                          (bi, ti, 0, 0)),                     # new row
             pl.BlockSpec(memory_space=pl.ANY),                # pool
             s_in_spec,                                        # scale pool
         ],
         out_specs=[spec, s_spec],
     )
-    return pl.pallas_call(
+    out, s_out = pl.pallas_call(
         functools.partial(_quant_paged_scatter_kernel, mode=mode, ps=ps,
                           nb=nb, rows=rows),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct(scales.shape, scales.dtype)],
-        # operands: (page_table, starts, valids, new, pool, scales)
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=[jax.ShapeDtypeStruct(_flat(pool).shape, pool.dtype),
+                   jax.ShapeDtypeStruct(_flat(scales).shape, scales.dtype)],
+        # operands: (page_table, starts, valids, layer, new, pool, scales)
+        input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
         name="quant_paged_cache_update",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      valids.astype(jnp.int32), new, pool, scales)
+      valids.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      new, _flat(pool), _flat(scales))
+    return out.reshape(pool.shape), s_out.reshape(scales.shape)

@@ -49,14 +49,17 @@ def cache_update(cache: jnp.ndarray, new: jnp.ndarray, slots: jnp.ndarray,
                                interpret=impl == "pallas_interpret")
 
 
-def paged_cache_update(pool: jnp.ndarray, new: jnp.ndarray,
+def paged_cache_update(pool: jnp.ndarray, layer, new: jnp.ndarray,
                        page_table: jnp.ndarray, starts: jnp.ndarray,
                        valids: jnp.ndarray, impl: str = "auto") -> jnp.ndarray:
     """Write ``new[b, t]`` at logical position ``starts[b] + t`` of row
-    ``b``'s paged cache, for ``t < valids[b]`` (masked rows land in the
-    scratch page 0, whose content is undefined).
+    ``b``'s paged cache in layer ``layer``, for ``t < valids[b]``
+    (masked rows land in the scratch page 0, whose content is
+    undefined).
 
-    pool: (P, page_size, *rest) physical pages shared by all rows.
+    pool: (L, P, page_size, *rest) a layer stack's physical pages,
+    shared by all rows (one unstacked pool is ``pool[None]``, layer 0).
+    layer: int32 scalar, may be traced.
     new: (B, T, *rest)   page_table: (B, NB) int32   starts/valids: (B,).
     One call covers both paged write paths: decode (T == 1) and chunked
     prefill (T == chunk).  Dispatches on ``PMT_CACHE_UPDATE_IMPL`` like
@@ -64,10 +67,12 @@ def paged_cache_update(pool: jnp.ndarray, new: jnp.ndarray,
     """
     impl = _resolve(impl)
     if impl == "lax":
-        return paged_cache_update_ref(pool, new, page_table, starts, valids)
+        return paged_cache_update_ref(pool, layer, new, page_table, starts,
+                                      valids)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown cache_update impl {impl!r}")
-    return paged_cache_update_pallas(pool, new, page_table, starts, valids,
+    return paged_cache_update_pallas(pool, layer, new, page_table, starts,
+                                     valids,
                                      interpret=impl == "pallas_interpret")
 
 
@@ -111,28 +116,30 @@ def quant_cache_update(cache: jnp.ndarray, scales: jnp.ndarray,
 
 
 def quant_paged_cache_update(pool: jnp.ndarray, scales: jnp.ndarray,
-                             new: jnp.ndarray, page_table: jnp.ndarray,
-                             starts: jnp.ndarray, valids: jnp.ndarray,
-                             mode: str, impl: str = "auto"):
+                             layer, new: jnp.ndarray,
+                             page_table: jnp.ndarray, starts: jnp.ndarray,
+                             valids: jnp.ndarray, mode: str,
+                             impl: str = "auto"):
     """Paged twin of :func:`quant_cache_update`: codes land in ``pool``
-    and scales in the page-aligned ``scales`` pool through the same
-    page-table indirection (masked rows -> scratch page 0 in both).
+    and scales in the page-aligned ``scales`` pool of layer ``layer``
+    through the same page-table indirection (masked rows -> scratch
+    page 0 in both).
 
-    pool: (P, page_size, *rest)   scales: (P, page_size, *rest[:-1])
-    new: (B, T, *rest)   page_table: (B, NB)   starts/valids: (B,).
-    Returns ``(pool, scales)``.
+    pool: (L, P, page_size, *rest)   scales: (L, P, page_size, *rest[:-1])
+    layer: int32 scalar   new: (B, T, *rest)   page_table: (B, NB)
+    starts/valids: (B,).  Returns ``(pool, scales)``.
     """
     impl = _resolve(impl)
     if impl == "lax":
-        return quant_paged_cache_update_ref(pool, scales, new, page_table,
-                                            starts, valids, mode)
+        return quant_paged_cache_update_ref(pool, scales, layer, new,
+                                            page_table, starts, valids, mode)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown cache_update impl {impl!r}")
-    p, ps = pool.shape[:2]
+    n, p, ps = pool.shape[:3]
     b, t = new.shape[:2]
-    h, d = _quant_heads(pool), pool.shape[-1]
+    h, d = _quant_heads(new), pool.shape[-1]
     out, s_out = quant_paged_cache_update_pallas(
-        pool.reshape(p, ps, h, d), scales.reshape(p, ps, h),
+        pool.reshape(n, p, ps, h, d), scales.reshape(n, p, ps, h), layer,
         new.reshape(b, t, h, d), page_table, starts, valids, mode,
         interpret=impl == "pallas_interpret")
     return out.reshape(pool.shape), s_out.reshape(scales.shape)

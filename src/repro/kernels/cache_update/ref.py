@@ -25,21 +25,23 @@ def cache_update_ref(cache: jnp.ndarray, new: jnp.ndarray,
     return jax.vmap(row)(cache, new, slots.astype(jnp.int32))
 
 
-def paged_cache_update_ref(pool: jnp.ndarray, new: jnp.ndarray,
+def paged_cache_update_ref(pool: jnp.ndarray, layer, new: jnp.ndarray,
                            page_table: jnp.ndarray, starts: jnp.ndarray,
                            valids: jnp.ndarray) -> jnp.ndarray:
-    """Paged-scatter oracle: one flat scatter into the page pool.
+    """Paged-scatter oracle: one flat scatter into the stacked pool.
 
-    pool: (P, page_size, *rest)  new: (B, T, *rest)
-    page_table: (B, NB) int32   starts/valids: (B,) int32.
+    pool: (L, P, page_size, *rest)  layer: int32 scalar in [0, L)
+    new: (B, T, *rest)  page_table: (B, NB) int32   starts/valids: (B,).
 
     Row ``t`` of ``new[b]`` lands at physical row
     ``page_table[b, (starts[b]+t) // ps] * ps + (starts[b]+t) % ps`` of
-    the flattened pool when ``t < valids[b]``; masked rows are routed to
-    scratch page 0 (row 0), whose content is undefined by contract —
-    parity tests compare pools *excluding* page 0.
+    layer ``layer``'s flattened pages when ``t < valids[b]``; masked
+    rows are routed to that layer's scratch page 0 (row 0), whose
+    content is undefined by contract — parity tests compare pools
+    *excluding* page 0.  The scatter addresses the whole stack, so no
+    layer is sliced out.
     """
-    p, ps = pool.shape[:2]
+    n, p, ps = pool.shape[:3]
     b, t = new.shape[:2]
     nb = page_table.shape[1]
     pos = starts.astype(jnp.int32)[:, None] + jnp.arange(t, dtype=jnp.int32)
@@ -49,7 +51,8 @@ def paged_cache_update_ref(pool: jnp.ndarray, new: jnp.ndarray,
     page = jnp.where(ok, jnp.take_along_axis(
         page_table.astype(jnp.int32), pos // ps, axis=1), 0)
     row = jnp.where(ok, pos % ps, 0)
-    flat = pool.reshape(p * ps, -1)
+    page = page + jnp.asarray(layer, jnp.int32) * p
+    flat = pool.reshape(n * p * ps, -1)
     out = flat.at[(page * ps + row).reshape(-1)].set(
         new.reshape(b * t, -1).astype(pool.dtype))
     return out.reshape(pool.shape)
@@ -69,15 +72,19 @@ def quant_cache_update_ref(cache: jnp.ndarray, scales: jnp.ndarray,
 
 
 def quant_paged_cache_update_ref(pool: jnp.ndarray, scales: jnp.ndarray,
-                                 new: jnp.ndarray, page_table: jnp.ndarray,
+                                 layer, new: jnp.ndarray,
+                                 page_table: jnp.ndarray,
                                  starts: jnp.ndarray, valids: jnp.ndarray,
                                  mode: str):
     """Paged quantizing twin: codes land in ``pool``, scales in the
     page-aligned ``scales`` pool (same page-id space, same masking).
 
-    pool: (P, page_size, *rest)  scales: (P, page_size, *rest[:-1])
-    new: (B, T, *rest)  page_table: (B, NB)  starts/valids: (B,) int32.
+    pool: (L, P, page_size, *rest)  scales: (L, P, page_size, *rest[:-1])
+    layer: int32 scalar   new: (B, T, *rest)  page_table: (B, NB)
+    starts/valids: (B,) int32.
     """
     codes, s = quant.quantize(new, mode)
-    return (paged_cache_update_ref(pool, codes, page_table, starts, valids),
-            paged_cache_update_ref(scales, s, page_table, starts, valids))
+    return (paged_cache_update_ref(pool, layer, codes, page_table, starts,
+                                   valids),
+            paged_cache_update_ref(scales, layer, s, page_table, starts,
+                                   valids))

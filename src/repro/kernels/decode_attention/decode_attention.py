@@ -207,32 +207,39 @@ def scratch(rows: tuple, hdv: int):
     ]
 
 
-def decode_attention_paged_pallas(q, k_pool, v_pool, page_table, lens, *,
-                                  window=None, softcap=None,
+def decode_attention_paged_pallas(q, k_pool, v_pool, layer, page_table, lens,
+                                  *, window=None, softcap=None,
                                   scale: float = 1.0, v_width=None,
                                   k_scale=None, v_scale=None,
                                   interpret: bool = False):
-    """Paged flash-decode: q (B, KVH, G, hdq) against physical page
-    pools k_pool/v_pool (P, page_size, KVH, hd*) through a
-    page_table (B, NB) int32.  lens: (B,) int32 new-token positions.
-    One kv block == one physical page (all kv heads); the K/V BlockSpec
-    index maps read the page table from scalar-prefetch SMEM — the paged
-    lookup is literally "the index map reads ``pt[b, block]`` instead of
-    ``(b, block)``", with the same clamp-to-elide-DMA trick on both
-    the beyond-``lens`` tail and (windowed) the below-window head.
+    """Paged flash-decode: q (B, KVH, G, hdq) against the stacked
+    physical page pools k_pool/v_pool (L, P, page_size, KVH, hd*) of a
+    layer stack, at layer ``layer`` (int32 scalar, may be traced),
+    through a page_table (B, NB) int32.  lens: (B,) int32 new-token
+    positions.  The stack is seen as one pool of ``L * P`` pages (a free
+    reshape, so it is read in place, never sliced) through the page
+    table offset by ``layer * P``.  One kv block == one physical page
+    (all kv heads); the K/V BlockSpec index maps read the page table
+    from scalar-prefetch SMEM — the paged lookup is literally "the index
+    map reads ``pt[b, block]`` instead of ``(b, block)``", with the
+    same clamp-to-elide-DMA trick on both the beyond-``lens`` tail and
+    (windowed) the below-window head.
     Returns (B, KVH, G, hdv) in q.dtype.  ``v_width``: read only the
     first lanes of v (``v_pool`` may alias ``k_pool`` — MLA).
-    ``k_scale``/``v_scale``: (P, page_size, KVH) float32 per-row absmax
-    scale pools for quantized code pools; they page through the same
-    table and clamp, and the kernel dequantizes in-register."""
+    ``k_scale``/``v_scale``: (L, P, page_size, KVH) float32 per-row
+    absmax scale pools for quantized code pools; they page through the
+    same table and clamp, and the kernel dequantizes in-register."""
     b, kvh, g, hdq = q.shape
-    ps = k_pool.shape[1]
+    p, ps = k_pool.shape[1:3]
     nb = page_table.shape[1]
     c = nb * ps
     hdv = v_width if v_width is not None else v_pool.shape[-1]
     quantized = k_scale is not None
     if quantized and v_scale is None:
         v_scale = k_scale
+    flat = lambda pool: pool.reshape((-1,) + pool.shape[2:])
+    page_table = page_table.astype(jnp.int32) + \
+        jnp.asarray(layer, jnp.int32) * p
 
     def q_map(bi, ki, lens, pt):
         return (bi, 0, 0, 0)
@@ -261,11 +268,11 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, page_table, lens, *,
         pl.BlockSpec((1, ps, kvh, hdq), kv_map),
         pl.BlockSpec((1, ps, kvh, hdv), kv_map),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [q, flat(k_pool), flat(v_pool)]
     if quantized:
         in_specs += [pl.BlockSpec((1, ps, kvh), scale_map),
                      pl.BlockSpec((1, ps, kvh), scale_map)]
-        operands += [k_scale, v_scale]
+        operands += [flat(k_scale), flat(v_scale)]
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window, softcap=softcap,
@@ -285,7 +292,7 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, page_table, lens, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(lens.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
+    )(lens.astype(jnp.int32), page_table, *operands)
 
 
 def decode_attention_pallas(q, k, v, lens, *, ring: bool = False,

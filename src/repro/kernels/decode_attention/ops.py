@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from repro.kernels.constants import NEG_INF
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_paged_pallas, decode_attention_pallas)
+from repro.kernels.decode_attention.ref import take_pages
 
 
 def _resolve(impl: str) -> str:
@@ -162,24 +163,25 @@ def decode_attention_lax(q, k, v, lens, *, ring: bool = False,
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def decode_attention_paged_lax(q, k_pool, v_pool, page_table, lens, *,
+def decode_attention_paged_lax(q, k_pool, v_pool, layer, page_table, lens, *,
                                window=None, softcap=None, scale: float = 1.0,
                                v_width=None, k_scale=None, v_scale=None):
     """Length-aware masked *paged* decode attention in plain XLA.
 
-    q (B, KVH, G, hdq); pools (P, page_size, KVH, *); page_table
-    (B, NB); lens (B,).  Same segment scheme as ``decode_attention_lax``
-    but each live segment first gathers its pages through the page
-    table (the gather is the XLA spelling of the kernel's index-map
-    indirection, and — like the kernel's clamp — it only happens for
-    segments the ``lax.cond`` actually runs, so the read/copy volume
-    still tracks the batch-max fill, not the pool size).  Paged caches
+    q (B, KVH, G, hdq); stacked pools (L, P, page_size, KVH, *) read at
+    layer ``layer``; page_table (B, NB); lens (B,).  Same segment scheme
+    as ``decode_attention_lax`` but each live segment first gathers its
+    pages through the page table (the gather is the XLA spelling of the
+    kernel's index-map indirection, and — like the kernel's clamp — it
+    only happens for segments the ``lax.cond`` actually runs, so the
+    read/copy volume still tracks the batch-max fill, not the pool
+    size).  Paged caches
     are unwrapped: sliding windows arrive as the explicit ``window``
     mask, which also lets segments wholly below the batch-min window
     start skip.
     """
     b, kvh, g, _ = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     nb = page_table.shape[1]
     c = nb * ps
     hdv = v_width if v_width is not None else v_pool.shape[-1]
@@ -194,22 +196,22 @@ def decode_attention_paged_lax(q, k_pool, v_pool, page_table, lens, *,
     seg_pages = -(-nb // _LAX_SEGMENTS)
 
     def seg_partial(pages, lo):
-        kp = jnp.take(k_pool, pages, axis=0)     # (B, sp, ps, KVH, hd)
+        kp = take_pages(k_pool, layer, pages)    # (B, sp, ps, KVH, hd)
         sp = pages.shape[1] * ps
         kf = kp.reshape(b, sp, kvh, -1).transpose(0, 2, 1, 3) \
             .astype(jnp.float32)                 # (B, KVH, S, hdq)
         if quantized:
-            ksp = jnp.take(k_scale, pages, axis=0)
+            ksp = take_pages(k_scale, layer, pages)
             kf = kf * ksp.reshape(b, sp, kvh).transpose(0, 2, 1) \
                 .astype(jnp.float32)[..., None]
         if alias and (not quantized or s_alias):
             vf = kf[..., :hdv]
         else:
-            vp = jnp.take(v_pool, pages, axis=0)
+            vp = take_pages(v_pool, layer, pages)
             vf = vp.reshape(b, sp, kvh, -1).transpose(0, 2, 1, 3) \
                 .astype(jnp.float32)
             if quantized:
-                vsp = jnp.take(v_scale, pages, axis=0)
+                vsp = take_pages(v_scale, layer, pages)
                 vf = vf * vsp.reshape(b, sp, kvh).transpose(0, 2, 1) \
                     .astype(jnp.float32)[..., None]
             vf = vf[..., :hdv]
@@ -259,20 +261,21 @@ def decode_attention_paged_lax(q, k_pool, v_pool, page_table, lens, *,
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def decode_attention_paged(q, k_pool, v_pool, page_table, cur_len, *,
+def decode_attention_paged(q, k_pool, v_pool, layer, page_table, cur_len, *,
                            window=None, softcap=None, scale: float = 1.0,
                            v_width=None, k_scale=None, v_scale=None,
                            impl: str = "auto"):
     """One-token decode attention over a *paged* cache.
 
     q: (B, 1, H, hdq) new-token queries.  k_pool/v_pool:
-    (P, page_size, KVH, hd*) physical pages shared by all rows, *after*
-    the new token's k/v landed (``paged_cache_update``).  page_table:
-    (B, NB) int32 logical block -> physical page.  cur_len: (B,) int32.
-    Paged caches store sliding-window layers unwrapped, so ``window``
-    is an explicit mask here (no ``ring``).  ``v_width`` as in
-    ``decode_attention``.  ``k_scale``/``v_scale``: (P, page_size, KVH)
-    float32 per-row scale pools when the code pools are quantized
+    (L, P, page_size, KVH, hd*) a layer stack's physical pages shared by
+    all rows, *after* the new token's k/v landed (``paged_cache_update``);
+    layer ``layer`` (int32 scalar, may be traced) is read in place.
+    page_table: (B, NB) int32 logical block -> physical page.  cur_len:
+    (B,) int32.  Paged caches store sliding-window layers unwrapped, so
+    ``window`` is an explicit mask here (no ``ring``).  ``v_width`` as
+    in ``decode_attention``.  ``k_scale``/``v_scale``: (L, P, page_size,
+    KVH) float32 per-row scale pools when the code pools are quantized
     (``v_scale`` defaults to ``k_scale`` — the MLA aliased cache).
     Returns (B, 1, H, hdv) in q.dtype.
     """
@@ -281,7 +284,7 @@ def decode_attention_paged(q, k_pool, v_pool, page_table, cur_len, *,
     if sq != 1:
         raise ValueError(f"decode_attention_paged takes one query token, "
                          f"got Sq={sq}")
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[3]
     if h % kvh:
         raise ValueError(f"H={h} not divisible by KVH={kvh}")
     g = h // kvh
@@ -290,11 +293,11 @@ def decode_attention_paged(q, k_pool, v_pool, page_table, cur_len, *,
     kw = dict(window=window, softcap=softcap, scale=scale, v_width=v_width,
               k_scale=k_scale, v_scale=v_scale)
     if impl == "lax":
-        out = decode_attention_paged_lax(qg, k_pool, v_pool, page_table,
-                                         lens, **kw)
+        out = decode_attention_paged_lax(qg, k_pool, v_pool, layer,
+                                         page_table, lens, **kw)
     elif impl in ("pallas", "pallas_interpret"):
         out = decode_attention_paged_pallas(
-            qg, k_pool, v_pool, page_table, lens,
+            qg, k_pool, v_pool, layer, page_table, lens,
             interpret=impl == "pallas_interpret", **kw)
     else:
         raise ValueError(f"unknown decode_attention impl {impl!r}")

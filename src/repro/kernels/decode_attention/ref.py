@@ -50,6 +50,15 @@ def pick_block_k(cache_size: int, block_k: int) -> int:
     return math.gcd(min(block_k, cache_size), cache_size)
 
 
+def take_pages(pool, layer, pages):
+    """Pages ``pages`` (int32, any shape) of layer ``layer`` of a stacked
+    (L, P, page_size, ...) pool, gathered from the whole stack: the
+    layer offsets the page ids, so no layer-sized slice is taken."""
+    n, p = pool.shape[:2]
+    flat = pool.reshape(n * p, *pool.shape[2:])
+    return jnp.take(flat, pages + jnp.asarray(layer, jnp.int32) * p, axis=0)
+
+
 def _block_step(q, k_blk, v_blk, k_lo, lens, m, l, acc, *,
                 cache_size: int, ring: bool, softcap, window=None,
                 ks_blk=None, vs_blk=None):
@@ -138,15 +147,15 @@ def decode_attention_ref(q, k, v, lens, *, ring: bool = False,
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def decode_attention_paged_ref(q, k_pool, v_pool, page_table, lens, *,
+def decode_attention_paged_ref(q, k_pool, v_pool, layer, page_table, lens, *,
                                window=None, softcap=None, scale: float = 1.0,
                                v_width=None, k_scale=None, v_scale=None):
     """Blockwise twin of the *paged* flash-decode kernel.
 
-    q: (B, KVH, G, hdq); k_pool/v_pool: (P, page_size, KVH, hd*)
-    physical pages (``v_pool`` may be ``k_pool`` with ``v_width`` set —
-    the MLA concatenated latent cache); page_table: (B, NB) int32;
-    lens: (B,) int32.
+    q: (B, KVH, G, hdq); k_pool/v_pool: (L, P, page_size, KVH, hd*)
+    stacked physical pages, of which layer ``layer`` is read (``v_pool``
+    may be ``k_pool`` with ``v_width`` set — the MLA concatenated latent
+    cache); page_table: (B, NB) int32; lens: (B,) int32.
 
     Gathers the logical (B, NB*page_size, KVH, *) view through the page
     table, then folds every page with ``block_k == page_size`` — the
@@ -157,15 +166,16 @@ def decode_attention_paged_ref(q, k_pool, v_pool, page_table, lens, *,
     windows arrive as the explicit ``window`` mask, never ``ring``.
     """
     b, kvh, g, _ = q.shape
-    p, ps = k_pool.shape[0], k_pool.shape[1]
+    ps = k_pool.shape[2]
     nb = page_table.shape[1]
     c = nb * ps
     pt = page_table.astype(jnp.int32)
-    k = jnp.take(k_pool, pt, axis=0).reshape(b, c, kvh, k_pool.shape[-1])
+    k = take_pages(k_pool, layer, pt).reshape(b, c, kvh, k_pool.shape[-1])
     if v_pool is k_pool:
         v = k
     else:
-        v = jnp.take(v_pool, pt, axis=0).reshape(b, c, kvh, v_pool.shape[-1])
+        v = take_pages(v_pool, layer, pt).reshape(b, c, kvh,
+                                                  v_pool.shape[-1])
     if v_width is not None:
         v = v[..., :v_width]
     hdv = v.shape[-1]
@@ -173,11 +183,11 @@ def decode_attention_paged_ref(q, k_pool, v_pool, page_table, lens, *,
     lens = jnp.broadcast_to(jnp.asarray(lens, jnp.int32), (b,))
     ks = vs = None
     if k_scale is not None:
-        ks = jnp.take(k_scale, pt, axis=0).reshape(b, c, kvh)
+        ks = take_pages(k_scale, layer, pt).reshape(b, c, kvh)
         if v_scale is None or v_scale is k_scale:
             vs = ks
         else:
-            vs = jnp.take(v_scale, pt, axis=0).reshape(b, c, kvh)
+            vs = take_pages(v_scale, layer, pt).reshape(b, c, kvh)
 
     def body(j, carry):
         m, l, acc = carry

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.constants import DEFAULT_BLOCK_K, NEG_INF
+from repro.kernels.decode_attention.ref import take_pages
 from repro.kernels.prefill_attention.prefill_attention import (
     prefill_attention_paged_pallas, prefill_attention_pallas)
 
@@ -117,44 +118,45 @@ def prefill_attention_lax(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,
 
 
 def prefill_attention_paged_lax(q, k_chunk, v_chunk, k_pool, v_pool,
-                                page_table, offs, *, window=None,
+                                layer, page_table, offs, *, window=None,
                                 softcap=None, scale: float = 1.0,
                                 v_width=None, k_scale=None, v_scale=None):
     """Fused masked *paged* chunk attention in plain XLA.
 
-    Gathers the logical (B, NB*page_size, KVH, *) cache view through
-    the page table — the XLA spelling of the kernel's index-map
-    indirection — then runs the same fused masked softmax as
-    ``prefill_attention_lax`` (chunked prefill is compute-bound, so
-    the one-gather copy is in the noise next to the T-query matmuls).
-    Paged caches are unwrapped: ``window`` is an explicit mask.
+    Gathers the logical (B, NB*page_size, KVH, *) cache view of layer
+    ``layer`` of the stacked pools through the page table — the XLA
+    spelling of the kernel's index-map indirection — then runs the same
+    fused masked softmax as ``prefill_attention_lax`` (chunked prefill
+    is compute-bound, so the one-gather copy is in the noise next to
+    the T-query matmuls).  Paged caches are unwrapped: ``window`` is an
+    explicit mask.
     """
     b, kvh, t, g, _ = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     nb = page_table.shape[1]
     pt = page_table.astype(jnp.int32)
-    k_cache = jnp.take(k_pool, pt, axis=0).reshape(b, nb * ps, kvh,
-                                                   k_pool.shape[-1])
+    k_cache = take_pages(k_pool, layer, pt).reshape(b, nb * ps, kvh,
+                                                    k_pool.shape[-1])
     if v_pool is k_pool:
         v_cache = k_cache
     else:
-        v_cache = jnp.take(v_pool, pt, axis=0).reshape(b, nb * ps, kvh,
-                                                       v_pool.shape[-1])
+        v_cache = take_pages(v_pool, layer, pt).reshape(b, nb * ps, kvh,
+                                                        v_pool.shape[-1])
     ks = vs = None
     if k_scale is not None:
-        ks = jnp.take(k_scale, pt, axis=0).reshape(b, nb * ps, kvh)
+        ks = take_pages(k_scale, layer, pt).reshape(b, nb * ps, kvh)
         if v_scale is None or v_scale is k_scale:
             vs = ks
         else:
-            vs = jnp.take(v_scale, pt, axis=0).reshape(b, nb * ps, kvh)
+            vs = take_pages(v_scale, layer, pt).reshape(b, nb * ps, kvh)
     return prefill_attention_lax(q, k_chunk, v_chunk, k_cache, v_cache,
                                  offs, ring=False, window=window,
                                  softcap=softcap, scale=scale,
                                  v_width=v_width, k_scale=ks, v_scale=vs)
 
 
-def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, page_table,
-                            offset, *, window=None, softcap=None,
+def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, layer,
+                            page_table, offset, *, window=None, softcap=None,
                             scale: float = 1.0, v_width=None,
                             k_scale=None, v_scale=None,
                             impl: str = "auto"):
@@ -162,13 +164,14 @@ def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, page_table,
 
     q: (B, T, H, hdq) chunk queries at positions ``offset[b] + i``.
     k_chunk/v_chunk: (B, T, KVH, *) — the chunk's own keys/values (NOT
-    yet scattered into the pool).  k_pool/v_pool: (P, page_size, KVH, *)
-    physical pages holding positions ``< offset[b]`` of every row,
+    yet scattered into the pool).  k_pool/v_pool: (L, P, page_size, KVH,
+    *) a layer stack's physical pages; layer ``layer`` (int32 scalar,
+    may be traced) holds positions ``< offset[b]`` of every row,
     addressed through page_table (B, NB) int32.  offset: scalar or (B,)
     int32.  Paged caches store sliding-window layers unwrapped, so
     ``window`` is an explicit mask (no ``ring``).  ``v_width`` as in
-    ``prefill_attention``.  ``k_scale``/``v_scale``: (P, page_size, KVH)
-    float32 per-row scale pools when the code pools are quantized
+    ``prefill_attention``.  ``k_scale``/``v_scale``: (L, P, page_size,
+    KVH) float32 per-row scale pools when the code pools are quantized
     (``v_scale`` defaults to ``k_scale``).  Returns (B, T, H, hdv) in
     q.dtype.
     """
@@ -177,7 +180,7 @@ def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, page_table,
     if k_chunk.shape[1] != t:
         raise ValueError(f"chunk keys cover {k_chunk.shape[1]} tokens but "
                          f"the query chunk has {t}")
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[3]
     if h % kvh:
         raise ValueError(f"H={h} not divisible by KVH={kvh}")
     g = h // kvh
@@ -187,10 +190,11 @@ def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, page_table,
               k_scale=k_scale, v_scale=v_scale)
     if impl == "lax":
         out = prefill_attention_paged_lax(qg, k_chunk, v_chunk, k_pool,
-                                          v_pool, page_table, offs, **kw)
+                                          v_pool, layer, page_table, offs,
+                                          **kw)
     elif impl in ("pallas", "pallas_interpret"):
         out = prefill_attention_paged_pallas(
-            qg, k_chunk, v_chunk, k_pool, v_pool, page_table, offs,
+            qg, k_chunk, v_chunk, k_pool, v_pool, layer, page_table, offs,
             interpret=impl == "pallas_interpret", **kw)
     else:
         raise ValueError(f"unknown prefill_attention impl {impl!r}")

@@ -199,22 +199,25 @@ def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
 
 
 def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
-                                   page_table, offs, *, window=None,
+                                   layer, page_table, offs, *, window=None,
                                    softcap=None, scale: float = 1.0,
                                    v_width=None, k_scale=None, v_scale=None,
                                    interpret: bool = False):
     """Paged chunked-prefill: q (B, KVH, T, G, hdq); chunk k/v
-    (B, T, KVH, *); physical pools (P, page_size, KVH, *) addressed
-    through page_table (B, NB) int32; offs (B,) int32.  The cache-phase
-    BlockSpec index maps read the page table from scalar-prefetch SMEM
-    (one block == one page, all kv heads) with the same clamp-to-elide-
-    DMA trick as the contiguous kernel.  Paged caches are unwrapped:
+    (B, T, KVH, *); the stacked physical pools (L, P, page_size, KVH, *)
+    of a layer stack, read at layer ``layer`` (int32 scalar, may be
+    traced) through page_table (B, NB) int32; offs (B,) int32.  The
+    stack is seen as one pool of ``L * P`` pages (a free reshape, so it
+    is read in place) through the page table offset by ``layer * P``.
+    The cache-phase BlockSpec index maps read the page table from
+    scalar-prefetch SMEM (one block == one page, all kv heads) with the
+    same clamp-to-elide-DMA trick as the contiguous kernel.  Paged caches are unwrapped:
     sliding windows arrive as the explicit ``window`` mask, never
-    ``ring``.  ``k_scale``/``v_scale``: (P, page_size, KVH) float32
+    ``ring``.  ``k_scale``/``v_scale``: (L, P, page_size, KVH) float32
     per-row scale pools when the code pools are quantized (chunk k/v
     stay full precision).  Returns (B, KVH, T, G, hdv) in q.dtype."""
     b, kvh, t, g, hdq = q.shape
-    ps = k_pool.shape[1]
+    p, ps = k_pool.shape[1:3]
     nb = page_table.shape[1]
     c = nb * ps
     hdv = v_width if v_width is not None else v_pool.shape[-1]
@@ -227,6 +230,9 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     quantized = k_scale is not None
     if quantized and v_scale is None:
         v_scale = k_scale
+    flat = lambda pool: pool.reshape((-1,) + pool.shape[2:])
+    page_table = page_table.astype(jnp.int32) + \
+        jnp.asarray(layer, jnp.int32) * p
 
     def q_map(bi, ki, offs, pt):
         return (bi, 0, 0, 0, 0)
@@ -261,11 +267,11 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
         pl.BlockSpec((1, ps, kvh, hdq), cache_map),
         pl.BlockSpec((1, ps, kvh, hdv), cache_map),
     ]
-    operands = [q, k_chunk, v_chunk, k_pool, v_pool]
+    operands = [q, k_chunk, v_chunk, flat(k_pool), flat(v_pool)]
     if quantized:
         in_specs += [pl.BlockSpec((1, ps, kvh), scale_map),
                      pl.BlockSpec((1, ps, kvh), scale_map)]
-        operands += [k_scale, v_scale]
+        operands += [flat(k_scale), flat(v_scale)]
 
     kernel = functools.partial(
         _paged_prefill_kernel, scale=scale, window=window, softcap=softcap,
@@ -286,7 +292,7 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_prefill_attention",
-    )(offs.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
+    )(offs.astype(jnp.int32), page_table, *operands)
 
 
 def prefill_attention_pallas(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.constants import DEFAULT_BLOCK_K, NEG_INF
-from repro.kernels.decode_attention.ref import pick_block_k
+from repro.kernels.decode_attention.ref import pick_block_k, take_pages
 
 __all__ = ["prefill_attention_ref", "pick_block_k"]
 
@@ -161,15 +161,16 @@ def prefill_attention_ref(q, k_chunk, v_chunk, k_cache, v_cache, offs, *,
 
 
 def prefill_attention_paged_ref(q, k_chunk, v_chunk, k_pool, v_pool,
-                                page_table, offs, *, window=None,
+                                layer, page_table, offs, *, window=None,
                                 softcap=None, scale: float = 1.0,
                                 v_width=None, k_scale=None, v_scale=None):
     """Blockwise twin of the *paged* chunked-prefill kernel.
 
     q: (B, KVH, T, G, hdq); k_chunk/v_chunk: (B, T, KVH, *);
-    k_pool/v_pool: (P, page_size, KVH, *) physical pages (``v_pool``
-    may be ``k_pool`` with ``v_width`` — MLA); page_table: (B, NB);
-    offs: (B,) int32 chunk start positions.
+    k_pool/v_pool: (L, P, page_size, KVH, *) stacked physical pages, of
+    which layer ``layer`` is read (``v_pool`` may be ``k_pool`` with
+    ``v_width`` — MLA); page_table: (B, NB); offs: (B,) int32 chunk
+    start positions.
 
     Gathers the logical cache view through the page table and sweeps it
     with cache blocks of exactly one page — the paged kernel's blocking
@@ -178,26 +179,26 @@ def prefill_attention_paged_ref(q, k_chunk, v_chunk, k_pool, v_pool,
     caches are unwrapped: ``window`` is an explicit mask, never a ring.
     """
     b, kvh, t, g, _ = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     nb = page_table.shape[1]
     pt = page_table.astype(jnp.int32)
-    k_cache = jnp.take(k_pool, pt, axis=0).reshape(b, nb * ps, kvh,
-                                                   k_pool.shape[-1])
+    k_cache = take_pages(k_pool, layer, pt).reshape(b, nb * ps, kvh,
+                                                    k_pool.shape[-1])
     if v_pool is k_pool:
         v_cache = k_cache
     else:
-        v_cache = jnp.take(v_pool, pt, axis=0).reshape(b, nb * ps, kvh,
-                                                       v_pool.shape[-1])
+        v_cache = take_pages(v_pool, layer, pt).reshape(b, nb * ps, kvh,
+                                                        v_pool.shape[-1])
     if v_width is not None:
         v_cache = v_cache[..., :v_width]
         v_chunk = v_chunk[..., :v_width]
     ks = vs = None
     if k_scale is not None:
-        ks = jnp.take(k_scale, pt, axis=0).reshape(b, nb * ps, kvh)
+        ks = take_pages(k_scale, layer, pt).reshape(b, nb * ps, kvh)
         if v_scale is None or v_scale is k_scale:
             vs = ks
         else:
-            vs = jnp.take(v_scale, pt, axis=0).reshape(b, nb * ps, kvh)
+            vs = take_pages(v_scale, layer, pt).reshape(b, nb * ps, kvh)
     return prefill_attention_ref(q, k_chunk, v_chunk, k_cache, v_cache,
                                  offs, ring=False, window=window,
                                  softcap=softcap, scale=scale, block_k=ps,

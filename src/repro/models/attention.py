@@ -476,13 +476,15 @@ def init_paged_kv_pools(cfg: ModelConfig, num_pages: int, page_size: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def paged_decode_self_attention(cfg: ModelConfig, p, x, cache, cur_len,
-                                page_table, *,
+def paged_decode_self_attention(cfg: ModelConfig, p, x, cache, layer,
+                                cur_len, page_table, *,
                                 window: Optional[int] = None,
                                 cache_impl: str = "auto"):
     """One-token decode against a *paged* cache.
 
-    x: (B, 1, d).  cache: {"k","v"} (P, page_size, KVH, hd) pools.
+    x: (B, 1, d).  cache: {"k","v"} (L, P, page_size, KVH, hd) pools of
+    a layer stack, of which this layer is ``layer`` (int32 scalar, may
+    be traced): read and written in place, never sliced out.
     cur_len: (B,) per-row position counters (paged serving is always
     continuous).  page_table: (B, NB) int32 — rows the scheduler has
     masked to 0 (mid-prefill / dead slots) read and write only the
@@ -503,19 +505,19 @@ def paged_decode_self_attention(cfg: ModelConfig, p, x, cache, cur_len,
     ones = jnp.ones((b,), jnp.int32)
     if mode is not None:
         k, ks = cu_ops.quant_paged_cache_update(
-            cache["k"], cache["k_scale"], k_new, page_table, cur, ones,
-            mode, impl=cache_impl)
+            cache["k"], cache["k_scale"], layer, k_new, page_table, cur,
+            ones, mode, impl=cache_impl)
         v, vs = cu_ops.quant_paged_cache_update(
-            cache["v"], cache["v_scale"], v_new, page_table, cur, ones,
-            mode, impl=cache_impl)
+            cache["v"], cache["v_scale"], layer, v_new, page_table, cur,
+            ones, mode, impl=cache_impl)
     else:
-        k = cu_ops.paged_cache_update(cache["k"], k_new, page_table, cur,
-                                      ones, impl=cache_impl)
-        v = cu_ops.paged_cache_update(cache["v"], v_new, page_table, cur,
-                                      ones, impl=cache_impl)
+        k = cu_ops.paged_cache_update(cache["k"], layer, k_new, page_table,
+                                      cur, ones, impl=cache_impl)
+        v = cu_ops.paged_cache_update(cache["v"], layer, v_new, page_table,
+                                      cur, ones, impl=cache_impl)
     scale = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or cfg.head_dim)
     o = da_ops.decode_attention_paged(
-        q, k, v, page_table, cur, window=window,
+        q, k, v, layer, page_table, cur, window=window,
         softcap=cfg.attn_softcap, scale=scale, k_scale=ks, v_scale=vs)
     new_cache = {"k": k, "v": v}
     if mode is not None:
@@ -523,14 +525,15 @@ def paged_decode_self_attention(cfg: ModelConfig, p, x, cache, cur_len,
     return output_proj(p, o), new_cache
 
 
-def paged_prefill_chunk_self_attention(cfg: ModelConfig, p, x, cache,
+def paged_prefill_chunk_self_attention(cfg: ModelConfig, p, x, cache, layer,
                                        offset, valid_len, page_table, *,
                                        window: Optional[int] = None,
                                        cache_impl: str = "auto"):
     """One chunk of chunked prefill through one attention layer, paged.
 
-    x: (B, T, d) at absolute positions ``offset[b] + i``; cache pools
-    hold positions ``< offset[b]`` of every row through page_table
+    x: (B, T, d) at absolute positions ``offset[b] + i``; layer
+    ``layer`` of the stacked (L, P, page_size, KVH, hd) cache pools
+    holds positions ``< offset[b]`` of every row through page_table
     (B, NB).  ``offset`` and ``valid_len`` are (B,) int32 — rows with
     ``valid_len == 0`` (slots decoding, or idle, during this batched
     admission dispatch) contribute garbage outputs the caller discards
@@ -549,22 +552,22 @@ def paged_prefill_chunk_self_attention(cfg: ModelConfig, p, x, cache,
     mode = cfg.kv_quant
     scale = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or cfg.head_dim)
     o = pf_ops.prefill_attention_paged(
-        q, k_new, v_new, cache["k"], cache["v"], page_table, off,
+        q, k_new, v_new, cache["k"], cache["v"], layer, page_table, off,
         window=window, softcap=cfg.attn_softcap, scale=scale,
         k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"))
     valids = jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (b,))
     if mode is not None:
         k, ks = cu_ops.quant_paged_cache_update(
-            cache["k"], cache["k_scale"], k_new, page_table, off, valids,
-            mode, impl=cache_impl)
+            cache["k"], cache["k_scale"], layer, k_new, page_table, off,
+            valids, mode, impl=cache_impl)
         v, vs = cu_ops.quant_paged_cache_update(
-            cache["v"], cache["v_scale"], v_new, page_table, off, valids,
-            mode, impl=cache_impl)
+            cache["v"], cache["v_scale"], layer, v_new, page_table, off,
+            valids, mode, impl=cache_impl)
         return output_proj(p, o), {"k": k, "v": v,
                                    "k_scale": ks, "v_scale": vs}
-    k = cu_ops.paged_cache_update(cache["k"], k_new, page_table, off,
+    k = cu_ops.paged_cache_update(cache["k"], layer, k_new, page_table, off,
                                   valids, impl=cache_impl)
-    v = cu_ops.paged_cache_update(cache["v"], v_new, page_table, off,
+    v = cu_ops.paged_cache_update(cache["v"], layer, v_new, page_table, off,
                                   valids, impl=cache_impl)
     return output_proj(p, o), {"k": k, "v": v}
 

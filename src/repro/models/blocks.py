@@ -420,34 +420,36 @@ def _block_tail(cfg: ModelConfig, p, x, out):
     return x + out
 
 
-def block_paged_decode(cfg: ModelConfig, p, x, cache, cur_len, page_table,
-                       idx: int):
+def block_paged_decode(cfg: ModelConfig, p, x, cache, layer, cur_len,
+                       page_table, idx: int):
     """One-token decode through one block against paged pools.
-    x: (B,1,d); cur_len: (B,); page_table: (B, NB)."""
+    x: (B,1,d); cache: stacked (L, P, ...) pools, this block's at index
+    ``layer``; cur_len: (B,); page_table: (B, NB)."""
     h = layers.apply_norm(cfg, p["norm_1"], x)
     if cfg.attention == "mla":
         out, cache = mla.mla_paged_decode_attention(cfg, p["mixer"], h,
-                                                    cache, cur_len,
+                                                    cache, layer, cur_len,
                                                     page_table)
     else:
         out, cache = attn.paged_decode_self_attention(
-            cfg, p["mixer"], h, cache, cur_len, page_table,
+            cfg, p["mixer"], h, cache, layer, cur_len, page_table,
             window=layer_window(cfg, idx))
     return _block_tail(cfg, p, x, out), cache
 
 
-def block_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, offset,
+def block_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
                               valid_len, page_table, idx: int):
     """One prefill chunk through one block against paged pools.
-    x: (B, T, d); offset/valid_len: (B,); page_table: (B, NB)."""
+    x: (B, T, d); cache: stacked (L, P, ...) pools, this block's at
+    index ``layer``; offset/valid_len: (B,); page_table: (B, NB)."""
     h = layers.apply_norm(cfg, p["norm_1"], x)
     if cfg.attention == "mla":
         out, cache = mla.mla_paged_prefill_chunk(cfg, p["mixer"], h, cache,
-                                                 offset, valid_len,
+                                                 layer, offset, valid_len,
                                                  page_table)
     else:
         out, cache = attn.paged_prefill_chunk_self_attention(
-            cfg, p["mixer"], h, cache, offset, valid_len, page_table,
+            cfg, p["mixer"], h, cache, layer, offset, valid_len, page_table,
             window=layer_window(cfg, idx))
     return _block_tail(cfg, p, x, out), cache
 

@@ -235,15 +235,16 @@ def init_paged_mla_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     return {"kv": jnp.zeros((num_pages, page_size, width), dtype)}
 
 
-def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, cur_len,
-                               page_table, cache_impl: str = "auto"):
+def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, layer,
+                               cur_len, page_table, cache_impl: str = "auto"):
     """One-token absorbed-MLA decode against a *paged* latent cache.
 
-    x: (B, 1, d); cache: {"kv"} (P, page_size, r + rope) pool;
-    cur_len: (B,); page_table: (B, NB) int32 (masked rows touch only
-    the scratch page).  The absorbed trick carries over unchanged: the
-    pool row is both key and (``v_width``-truncated) value, viewed as
-    (P, page_size, 1, r + rope) for the kernels' KVH axis.
+    x: (B, 1, d); cache: {"kv"} (L, P, page_size, r + rope) pools of a
+    layer stack, this layer at index ``layer``; cur_len: (B,);
+    page_table: (B, NB) int32 (masked rows touch only the scratch
+    page).  The absorbed trick carries over unchanged: the pool row is
+    both key and (``v_width``-truncated) value, viewed as
+    (L, P, page_size, 1, r + rope) for the kernels' KVH axis.
     """
     from repro.kernels.cache_update import ops as cu_ops
     from repro.kernels.decode_attention import ops as da_ops
@@ -262,20 +263,21 @@ def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, cur_len,
     ones = jnp.ones((b,), jnp.int32)
     if mode is not None:
         kv, sc = cu_ops.quant_paged_cache_update(
-            cache["kv"], cache["kv_scale"], kv_new, page_table, cur, ones,
-            mode, impl=cache_impl)
+            cache["kv"], cache["kv_scale"], layer, kv_new, page_table, cur,
+            ones, mode, impl=cache_impl)
     else:
-        kv = cu_ops.paged_cache_update(cache["kv"], kv_new, page_table, cur,
-                                       ones, impl=cache_impl)
+        kv = cu_ops.paged_cache_update(cache["kv"], layer, kv_new,
+                                       page_table, cur, ones,
+                                       impl=cache_impl)
 
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"].astype(dt))
     q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)          # (B,1,H,r+rr)
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
-    kv4 = kv[:, :, None, :]                                    # (P,ps,1,r+rr)
+    kv4 = kv[..., None, :]                                   # (L,P,ps,1,r+rr)
     ctx = da_ops.decode_attention_paged(
-        q_eff, kv4, kv4, page_table, cur, scale=1.0 / math.sqrt(qk_hd),
-        v_width=m.kv_lora_rank,
-        k_scale=sc[:, :, None] if mode is not None else None
+        q_eff, kv4, kv4, layer, page_table, cur,
+        scale=1.0 / math.sqrt(qk_hd), v_width=m.kv_lora_rank,
+        k_scale=sc[..., None] if mode is not None else None
     ).astype(dt)                                               # (B,1,H,r)
 
     o = jnp.einsum("bqhr,rhk->bqhk", ctx, p["wv_b"].astype(dt))
@@ -285,12 +287,13 @@ def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, cur_len,
                  else {"kv": kv})
 
 
-def mla_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, offset, valid_len,
-                            page_table, cache_impl: str = "auto"):
+def mla_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
+                            valid_len, page_table, cache_impl: str = "auto"):
     """One chunk of chunked prefill through one MLA layer, paged.
 
     Mirrors ``mla_prefill_chunk`` with the pool view in place of the
-    per-slot cache: offset/valid_len are (B,) (rows with
+    per-slot cache: layer ``layer`` of the stacked (L, P, page_size,
+    r + rope) pool; offset/valid_len are (B,) (rows with
     ``valid_len == 0`` are masked to the scratch page and discarded by
     the caller).  Attend first — the chunk's own rows arrive as
     separate operands — then scatter.
@@ -312,21 +315,22 @@ def mla_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, offset, valid_len,
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
     mode = cfg.kv_quant
     kvx = kv_new[:, :, None, :]                                # (B,T,1,r+rr)
-    kvp = cache["kv"][:, :, None, :]                           # (P,ps,1,r+rr)
+    kvp = cache["kv"][..., None, :]                          # (L,P,ps,1,r+rr)
     ctx = pf_ops.prefill_attention_paged(
-        q_eff, kvx, kvx, kvp, kvp, page_table, off,
+        q_eff, kvx, kvx, kvp, kvp, layer, page_table, off,
         scale=1.0 / math.sqrt(qk_hd), v_width=m.kv_lora_rank,
-        k_scale=(cache["kv_scale"][:, :, None] if mode is not None
+        k_scale=(cache["kv_scale"][..., None] if mode is not None
                  else None)).astype(dt)                        # (B,T,H,r)
 
     valids = jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (b,))
     if mode is not None:
         kv, sc = cu_ops.quant_paged_cache_update(
-            cache["kv"], cache["kv_scale"], kv_new, page_table, off,
+            cache["kv"], cache["kv_scale"], layer, kv_new, page_table, off,
             valids, mode, impl=cache_impl)
     else:
-        kv = cu_ops.paged_cache_update(cache["kv"], kv_new, page_table, off,
-                                       valids, impl=cache_impl)
+        kv = cu_ops.paged_cache_update(cache["kv"], layer, kv_new,
+                                       page_table, off, valids,
+                                       impl=cache_impl)
     o = jnp.einsum("bqhr,rhk->bqhk", ctx, p["wv_b"].astype(dt))
     out = jnp.einsum("bqhk,hkd->bqd", o, p["wo"].astype(dt))
     out = shard(out, "batch", "seq", "d_model")

@@ -636,43 +636,52 @@ def make_paged_serve_fns(cfg: ModelConfig):
     lay = unit_layout(cfg)
 
     def _run_stack(params, caches, x, run):
-        """Shared prefix + scanned-units sweep for both paged steps."""
+        """Shared prefix + units sweep for both paged steps.
+
+        Every pool stays whole across the sweep: the kernels address
+        layer ``u`` of a stacked (L, P, page_size, ...) leaf through a
+        layer index, so the scan carries the unit pools and no step
+        slices, copies or restacks a pool.  An unstacked leaf (a prefix
+        layer, or the single unit) runs as a stack of one, a free
+        reshape."""
+        stack1 = lambda tree: jax.tree.map(lambda a: a[None], tree)
+        unstack1 = lambda tree: jax.tree.map(lambda a: a[0], tree)
         if lay.prefix:
             for i in lay.prefix:
                 x, c = run(x, params["prefix"][f"l{i}"],
-                           caches["prefix"][f"l{i}"], i)
-                caches["prefix"][f"l{i}"] = c
+                           stack1(caches["prefix"][f"l{i}"]), 0, i)
+                caches["prefix"][f"l{i}"] = unstack1(c)
 
-        def unit(xx, up_uc):
-            up, uc = up_uc
-            new_uc = {}
+        def unit(xx, up, uc, u):
+            uc = dict(uc)
             for r in range(lay.unit_len):
-                xx, c = run(xx, up[f"r{r}"], uc[f"r{r}"],
-                            lay.prefix_len + r)
-                new_uc[f"r{r}"] = c
-            return xx, new_uc
+                xx, uc[f"r{r}"] = run(xx, up[f"r{r}"], uc[f"r{r}"], u,
+                                      lay.prefix_len + r)
+            return xx, uc
 
         if lay.n_units == 1:
-            x, caches["units"] = unit(x, (params["units"], caches["units"]))
+            x, uc = unit(x, params["units"], stack1(caches["units"]), 0)
+            caches["units"] = unstack1(uc)
         elif cfg.scan_layers:
-            x, caches["units"] = jax.lax.scan(
-                lambda xx, up_uc: unit(xx, up_uc), x,
-                (params["units"], caches["units"]))
+            def body(carry, up_u):
+                (xx, uc), (up, u) = carry, up_u
+                return unit(xx, up, uc, u), None
+
+            (x, caches["units"]), _ = jax.lax.scan(
+                body, (x, caches["units"]),
+                (params["units"], jnp.arange(lay.n_units, dtype=jnp.int32)))
         else:
-            ucs = []
             for u in range(lay.n_units):
-                sl = lambda a: a[u]
-                x, uc = unit(x, (jax.tree.map(sl, params["units"]),
-                                 jax.tree.map(sl, caches["units"])))
-                ucs.append(uc)
-            caches["units"] = jax.tree.map(lambda *xs: jnp.stack(xs), *ucs)
+                x, caches["units"] = unit(
+                    x, jax.tree.map(lambda a: a[u], params["units"]),
+                    caches["units"], u)
         return layers.apply_norm(cfg, params["final_norm"], x), caches
 
     def decode(params, caches, tokens, cur_len, page_table):
         x = layers.embed_tokens(cfg, params["embed"], tokens)
 
-        def run(xx, bp, c, idx):
-            return blocks.block_paged_decode(cfg, bp, xx, c, cur_len,
+        def run(xx, bp, c, layer, idx):
+            return blocks.block_paged_decode(cfg, bp, xx, c, layer, cur_len,
                                              page_table, idx)
 
         x, caches = _run_stack(params, caches, x, run)
@@ -684,9 +693,9 @@ def make_paged_serve_fns(cfg: ModelConfig):
         valid_len = jnp.maximum(last_idx + 1, 0)
         x = layers.embed_tokens(cfg, params["embed"], tokens)
 
-        def run(xx, bp, c, idx):
+        def run(xx, bp, c, layer, idx):
             return blocks.block_paged_prefill_chunk(
-                cfg, bp, xx, c, offset, valid_len, page_table, idx)
+                cfg, bp, xx, c, layer, offset, valid_len, page_table, idx)
 
         x, caches = _run_stack(params, caches, x, run)
         # per-row last real token (vector last_idx — batched admissions)

@@ -45,6 +45,9 @@ MODES = list(quant.QUANT_MODES)
 B, C, T, KVH, G, HD = 3, 64, 8, 2, 4, 16
 PS, NB, P = 8, 8, 32
 RK = 24          # MLA latent+rope width (v_width=8 slice alias)
+# Paged pools are layer stacks (L, P, PS, ...) read and written at one
+# layer: a stack of one, and an inner layer of three.
+STACKS = [(1, 0), (3, 1)]
 
 
 def rng(i):
@@ -125,24 +128,29 @@ def test_quant_cache_update_parity(mode, heads):
         close(deq[b, s], row, atol=tol)
 
 
+@pytest.mark.parametrize("n_layers,layer", STACKS)
 @pytest.mark.parametrize("mode", MODES)
-def test_quant_paged_cache_update_parity(mode):
+def test_quant_paged_cache_update_parity(mode, n_layers, layer):
     r = rng(2)
-    pool = quant.quantize(
-        jnp.asarray(r.normal(size=(P, PS, KVH, HD)), jnp.float32), mode)[0]
-    scales = jnp.zeros((P, PS, KVH), jnp.float32)
+    pool = quant.quantize(jnp.asarray(
+        r.normal(size=(n_layers, P, PS, KVH, HD)), jnp.float32), mode)[0]
+    scales = jnp.asarray(r.uniform(size=(n_layers, P, PS, KVH)), jnp.float32)
     new = jnp.asarray(r.normal(size=(B, T, KVH, HD)) * 2, jnp.float32)
     pt = jnp.asarray(r.permutation(P - 1)[: B * NB].reshape(B, NB) + 1,
                      jnp.int32)
     starts = jnp.asarray([0, 5, 30], jnp.int32)
     valids = jnp.asarray([T, 4, T], jnp.int32)
-    ref_p, ref_s = quant_paged_cache_update_ref(pool, scales, new, pt,
-                                                starts, valids, mode)
-    out_p, out_s = quant_paged_cache_update(pool, scales, new, pt, starts,
-                                            valids, mode,
+    ref_p, ref_s = quant_paged_cache_update_ref(
+        pool[layer:layer + 1], scales[layer:layer + 1], 0, new, pt, starts,
+        valids, mode)
+    out_p, out_s = quant_paged_cache_update(pool, scales, layer, new, pt,
+                                            starts, valids, mode,
                                             impl="pallas_interpret")
-    bitexact(ref_p, out_p)
-    bitexact(ref_s, out_s)
+    bitexact(ref_p[0], out_p[layer])
+    bitexact(ref_s[0], out_s[layer])
+    others = [i for i in range(n_layers) if i != layer]
+    bitexact(np.asarray(out_p)[others], np.asarray(pool)[others])
+    bitexact(np.asarray(out_s)[others], np.asarray(scales)[others])
 
 
 # -- decode attention: in-register dequant vs ref / lax -----------------------
@@ -188,45 +196,57 @@ def test_quant_decode_mla_alias(mode):
     close(ref, lx)
 
 
+def quantized_stack(r, n_layers, shape, mode, spread=1.0):
+    """Codes and scales of a (n_layers, *shape) pool of normal draws."""
+    x = jnp.asarray(r.normal(size=(n_layers, *shape)) * spread, jnp.float32)
+    return quant.quantize(x, mode)
+
+
+def at(layer, *pools):
+    """Each pool's ``[layer]`` as a stack of one (the one-pool contract)."""
+    return [p[layer:layer + 1] for p in pools]
+
+
+@pytest.mark.parametrize("n_layers,layer", STACKS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("window", [None, 24])
-def test_quant_decode_paged_parity(mode, window):
+def test_quant_decode_paged_parity(mode, window, n_layers, layer):
     r = rng(5)
-    kp = jnp.asarray(r.normal(size=(P, PS, KVH, HD)) * 2, jnp.float32)
-    vp = jnp.asarray(r.normal(size=(P, PS, KVH, HD)), jnp.float32)
+    kpc, kps = quantized_stack(r, n_layers, (P, PS, KVH, HD), mode, 2)
+    vpc, vps = quantized_stack(r, n_layers, (P, PS, KVH, HD), mode)
     pt = jnp.asarray(r.permutation(P - 1)[: B * NB].reshape(B, NB) + 1,
                      jnp.int32)
     lens = jnp.asarray([3, 30, 62], jnp.int32)
-    kpc, kps = quant.quantize(kp, mode)
-    vpc, vps = quant.quantize(vp, mode)
     q2 = jnp.asarray(r.normal(size=(B, KVH, G, HD)), jnp.float32)
-    ref = decode_attention_paged_ref(q2, kpc, vpc, pt, lens, scale=0.3,
-                                     window=window, k_scale=kps, v_scale=vps)
-    pl = decode_attention_paged_pallas(q2, kpc, vpc, pt, lens, scale=0.3,
-                                       window=window, k_scale=kps,
+    k1, v1, ks1, vs1 = at(layer, kpc, vpc, kps, vps)
+    ref = decode_attention_paged_ref(q2, k1, v1, 0, pt, lens, scale=0.3,
+                                     window=window, k_scale=ks1, v_scale=vs1)
+    pl = decode_attention_paged_pallas(q2, kpc, vpc, layer, pt, lens,
+                                       scale=0.3, window=window, k_scale=kps,
                                        v_scale=vps, interpret=True)
-    lx = decode_attention_paged_lax(q2, kpc, vpc, pt, lens, scale=0.3,
+    lx = decode_attention_paged_lax(q2, kpc, vpc, layer, pt, lens, scale=0.3,
                                     window=window, k_scale=kps, v_scale=vps)
     bitexact(ref, pl)
     close(ref, lx)
 
 
+@pytest.mark.parametrize("n_layers,layer", STACKS)
 @pytest.mark.parametrize("mode", MODES)
-def test_quant_decode_paged_mla_alias(mode):
+def test_quant_decode_paged_mla_alias(mode, n_layers, layer):
     r = rng(6)
-    kvp = jnp.asarray(r.normal(size=(P, PS, 1, RK)), jnp.float32)
-    kvpc, kvps = quant.quantize(kvp, mode)
+    kvpc, kvps = quantized_stack(r, n_layers, (P, PS, 1, RK), mode)
     pt = jnp.asarray(r.permutation(P - 1)[: B * NB].reshape(B, NB) + 1,
                      jnp.int32)
     q3 = jnp.asarray(r.normal(size=(B, 1, G, RK)), jnp.float32)
     lens = jnp.asarray([3, 30, 62], jnp.int32)
-    ref = decode_attention_paged_ref(q3, kvpc, kvpc, pt, lens, scale=0.3,
-                                     v_width=8, k_scale=kvps)
-    pl = decode_attention_paged_pallas(q3, kvpc, kvpc, pt, lens, scale=0.3,
-                                       v_width=8, k_scale=kvps,
+    kv1, s1 = at(layer, kvpc, kvps)
+    ref = decode_attention_paged_ref(q3, kv1, kv1, 0, pt, lens, scale=0.3,
+                                     v_width=8, k_scale=s1)
+    pl = decode_attention_paged_pallas(q3, kvpc, kvpc, layer, pt, lens,
+                                       scale=0.3, v_width=8, k_scale=kvps,
                                        interpret=True)
-    lx = decode_attention_paged_lax(q3, kvpc, kvpc, pt, lens, scale=0.3,
-                                    v_width=8, k_scale=kvps)
+    lx = decode_attention_paged_lax(q3, kvpc, kvpc, layer, pt, lens,
+                                    scale=0.3, v_width=8, k_scale=kvps)
     bitexact(ref, pl)
     close(ref, lx)
 
@@ -278,50 +298,52 @@ def test_quant_prefill_mla_alias(mode):
     close(ref, lx)
 
 
+@pytest.mark.parametrize("n_layers,layer", STACKS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("window", [None, 24])
-def test_quant_prefill_paged_parity(mode, window):
+def test_quant_prefill_paged_parity(mode, window, n_layers, layer):
     r = rng(9)
-    kp = jnp.asarray(r.normal(size=(P, PS, KVH, HD)) * 2, jnp.float32)
-    vp = jnp.asarray(r.normal(size=(P, PS, KVH, HD)), jnp.float32)
+    kpc, kps = quantized_stack(r, n_layers, (P, PS, KVH, HD), mode, 2)
+    vpc, vps = quantized_stack(r, n_layers, (P, PS, KVH, HD), mode)
     pt = jnp.asarray(r.permutation(P - 1)[: B * NB].reshape(B, NB) + 1,
                      jnp.int32)
     kx = jnp.asarray(r.normal(size=(B, T, KVH, HD)), jnp.float32)
     vx = jnp.asarray(r.normal(size=(B, T, KVH, HD)), jnp.float32)
     q2 = jnp.asarray(r.normal(size=(B, KVH, T, G, HD)), jnp.float32)
     offs = jnp.asarray([0, 17, 55], jnp.int32)
-    kpc, kps = quant.quantize(kp, mode)
-    vpc, vps = quant.quantize(vp, mode)
-    ref = prefill_attention_paged_ref(q2, kx, vx, kpc, vpc, pt, offs,
+    k1, v1, ks1, vs1 = at(layer, kpc, vpc, kps, vps)
+    ref = prefill_attention_paged_ref(q2, kx, vx, k1, v1, 0, pt, offs,
                                       window=window, scale=0.3,
-                                      k_scale=kps, v_scale=vps)
-    pl = prefill_attention_paged_pallas(q2, kx, vx, kpc, vpc, pt, offs,
-                                        window=window, scale=0.3,
+                                      k_scale=ks1, v_scale=vs1)
+    pl = prefill_attention_paged_pallas(q2, kx, vx, kpc, vpc, layer, pt,
+                                        offs, window=window, scale=0.3,
                                         k_scale=kps, v_scale=vps,
                                         interpret=True)
-    lx = prefill_attention_paged_lax(q2, kx, vx, kpc, vpc, pt, offs,
+    lx = prefill_attention_paged_lax(q2, kx, vx, kpc, vpc, layer, pt, offs,
                                      window=window, scale=0.3,
                                      k_scale=kps, v_scale=vps)
     bitexact(ref, pl)
     close(ref, lx)
 
 
+@pytest.mark.parametrize("n_layers,layer", STACKS)
 @pytest.mark.parametrize("mode", MODES)
-def test_quant_prefill_paged_mla_alias(mode):
+def test_quant_prefill_paged_mla_alias(mode, n_layers, layer):
     r = rng(10)
     kvx = jnp.asarray(r.normal(size=(B, T, 1, RK)), jnp.float32)
-    kvp = jnp.asarray(r.normal(size=(P, PS, 1, RK)), jnp.float32)
-    kvpc, kvps = quant.quantize(kvp, mode)
+    kvpc, kvps = quantized_stack(r, n_layers, (P, PS, 1, RK), mode)
     pt = jnp.asarray(r.permutation(P - 1)[: B * NB].reshape(B, NB) + 1,
                      jnp.int32)
     q3 = jnp.asarray(r.normal(size=(B, 1, T, G, RK)), jnp.float32)
     offs = jnp.asarray([0, 17, 55], jnp.int32)
-    ref = prefill_attention_paged_ref(q3, kvx, kvx, kvpc, kvpc, pt, offs,
-                                      scale=0.3, v_width=8, k_scale=kvps)
-    pl = prefill_attention_paged_pallas(q3, kvx, kvx, kvpc, kvpc, pt, offs,
-                                        scale=0.3, v_width=8, k_scale=kvps,
-                                        interpret=True)
-    lx = prefill_attention_paged_lax(q3, kvx, kvx, kvpc, kvpc, pt, offs,
-                                     scale=0.3, v_width=8, k_scale=kvps)
+    kv1, s1 = at(layer, kvpc, kvps)
+    ref = prefill_attention_paged_ref(q3, kvx, kvx, kv1, kv1, 0, pt, offs,
+                                      scale=0.3, v_width=8, k_scale=s1)
+    pl = prefill_attention_paged_pallas(q3, kvx, kvx, kvpc, kvpc, layer, pt,
+                                        offs, scale=0.3, v_width=8,
+                                        k_scale=kvps, interpret=True)
+    lx = prefill_attention_paged_lax(q3, kvx, kvx, kvpc, kvpc, layer, pt,
+                                     offs, scale=0.3, v_width=8,
+                                     k_scale=kvps)
     bitexact(ref, pl)
     close(ref, lx)

@@ -8,9 +8,11 @@ and each test worker imports every test file) and asserts that the
 compiled program calls the kernel (``tpu_custom_call``).  Widths are
 qwen3-0.6b's (KVH=8, G=2, hd=128) unless a case says otherwise.
 """
+import dataclasses
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,7 @@ from repro.serve import engine as engine_mod
 B, C, T, PS = 8, 1024, 32, 16
 NB = C // PS
 P = B * NB + 1
+L = 3                               # layers in a stacked paged pool
 I32, BF16, F32, I8 = jnp.int32, jnp.bfloat16, jnp.float32, jnp.int8
 
 
@@ -81,7 +84,7 @@ ATTN = {
 
 
 def _scales(c, dtype):
-    return [S(c[:3], F32)] * 2 if dtype == I8 else []
+    return [S(c[:-1], F32)] * 2 if dtype == I8 else []
 
 
 @pytest.mark.parametrize("case", ["qwen3-bf16", "qwen3-int8", "smollm-bf16",
@@ -100,13 +103,13 @@ def test_decode_attention_compiles(one_chip, case, layout):
             v_scale=s[1] if s else None, **kw)
         text = _text(one_chip, fn, q, kv, kv, S((B,), I32), *sc)
     else:
-        kv = S((P, PS, kvh, hd), dt)
+        kv = S((L, P, PS, kvh, hd), dt)
         sc = _scales(kv.shape, dt)
-        fn = lambda q, k, v, pt, l, *s: decode_attention_paged_pallas(
-            q, k, v, pt, l, k_scale=s[0] if s else None,
+        fn = lambda q, k, v, i, pt, l, *s: decode_attention_paged_pallas(
+            q, k, v, i, pt, l, k_scale=s[0] if s else None,
             v_scale=s[1] if s else None, **kw)
-        text = _text(one_chip, fn, q, kv, kv, S((B, NB), I32), S((B,), I32),
-                     *sc)
+        text = _text(one_chip, fn, q, kv, kv, S((), I32), S((B, NB), I32),
+                     S((B,), I32), *sc)
     assert "tpu_custom_call" in text
 
 
@@ -127,14 +130,14 @@ def test_prefill_attention_compiles(one_chip, case, layout):
             v_scale=s[1] if s else None, **kw)
         text = _text(one_chip, fn, q, x, x, kv, kv, S((B,), I32), *sc)
     else:
-        kv = S((P, PS, kvh, hd), dt)
+        kv = S((L, P, PS, kvh, hd), dt)
         sc = _scales(kv.shape, dt)
-        fn = lambda q, kx, vx, kc, vc, pt, o, *s: \
+        fn = lambda q, kx, vx, kc, vc, i, pt, o, *s: \
             prefill_attention_paged_pallas(
-                q, kx, vx, kc, vc, pt, o, k_scale=s[0] if s else None,
+                q, kx, vx, kc, vc, i, pt, o, k_scale=s[0] if s else None,
                 v_scale=s[1] if s else None, **kw)
-        text = _text(one_chip, fn, q, x, x, kv, kv, S((B, NB), I32),
-                     S((B,), I32), *sc)
+        text = _text(one_chip, fn, q, x, x, kv, kv, S((), I32),
+                     S((B, NB), I32), S((B,), I32), *sc)
     assert "tpu_custom_call" in text
 
 
@@ -152,8 +155,9 @@ def test_cache_update_compiles(one_chip, rows, t):
                      S((B, 1) + rest, dt), S((B,), I32))
     else:
         text = _text(one_chip, paged_cache_update_pallas,
-                     S((P, PS) + rest, dt), S((B, t) + rest, dt),
-                     S((B, NB), I32), S((B,), I32), S((B,), I32))
+                     S((L, P, PS) + rest, dt), S((), I32),
+                     S((B, t) + rest, dt), S((B, NB), I32), S((B,), I32),
+                     S((B,), I32))
     assert "tpu_custom_call" in text
 
 
@@ -167,11 +171,12 @@ def test_quant_cache_update_compiles(one_chip, mode, t):
                      S((B, C, 8), F32), S((B, 1, 8, 128), BF16),
                      S((B,), I32))
     else:
-        fn = lambda c, s, n, pt, st, va: quant_paged_cache_update_pallas(
-            c, s, n, pt, st, va, mode)
-        text = _text(one_chip, fn, S((P, PS, 8, 128), codes),
-                     S((P, PS, 8), F32), S((B, t, 8, 128), BF16),
-                     S((B, NB), I32), S((B,), I32), S((B,), I32))
+        fn = lambda c, s, i, n, pt, st, va: quant_paged_cache_update_pallas(
+            c, s, i, n, pt, st, va, mode)
+        text = _text(one_chip, fn, S((L, P, PS, 8, 128), codes),
+                     S((L, P, PS, 8), F32), S((), I32),
+                     S((B, t, 8, 128), BF16), S((B, NB), I32), S((B,), I32),
+                     S((B,), I32))
     assert "tpu_custom_call" in text
 
 
@@ -217,3 +222,52 @@ def test_serve_steps_hold_every_kernel(one_chip, pallas_dispatch, layout):
         smoke.KERNELS[(layout, "decode")]
     assert smoke.check_kernels(prefill, layout, "prefill") == \
         smoke.KERNELS[(layout, "prefill")]
+
+
+# A pool larger than the chip's VMEM, as a deployed pool is: the
+# compiler is free to stage a small one through VMEM with copies of its
+# own, which would hide what the guard looks for.
+GUARD_PAGES = 4097
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+def test_paged_steps_keep_the_pool_in_place(one_chip, pallas_dispatch,
+                                            arch):
+    """The compiled paged decode and chunk programs at the arch's
+    published widths (2 layers, so the units run under the layer scan)
+    hold no copy, slice or update whose shape is a whole layer's pool
+    or the stacked pool: the kernels read and write the stacked pool in
+    place, aliased from input to output."""
+    cfg = dataclasses.replace(configs.get_config(arch), num_layers=2,
+                              param_dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda: model_mod.init_params(jax.random.PRNGKey(0), cfg)[0])
+    pools = jax.eval_shape(
+        lambda: model_mod.init_paged_caches(cfg, GUARD_PAGES, PS))
+    leaves = jax.tree.leaves(pools)
+    assert leaves[0].shape[0] == 2                  # stacked on "layers"
+    # the stacked pool, one layer's pool, and the stack as one pool of
+    # L * P pages, as the kernels see it
+    shapes = {tuple(a.shape) for a in leaves} | \
+        {tuple(a.shape[1:]) for a in leaves} | \
+        {(a.shape[0] * a.shape[1],) + tuple(a.shape[2:]) for a in leaves}
+    rows = S((B,), I32)
+    steps = {
+        "decode": (engine_mod.make_paged_decode_fn(cfg),
+                   (params, pools, S((B, 1), I32), rows, S((B, NB), I32))),
+        "prefill": (engine_mod.make_paged_prefill_chunk_fn(cfg),
+                    (params, pools, S((B, T), I32), rows, rows,
+                     S((B, NB), I32))),
+    }
+    op = re.compile(r"= \w+\[([0-9,]*)\]\S* ([a-z-]+)\(")
+    for name, (fn, shapes_in) in steps.items():
+        args = [jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), a) for a in shapes_in]
+        text = jax.jit(fn, donate_argnums=1).lower(*args).compile().as_text()
+        moved = [(kind, dims) for dims, kind in op.findall(text)
+                 if kind in ("copy", "copy-done", "dynamic-slice",
+                             "dynamic-update-slice", "fusion", "slice",
+                             "concatenate")
+                 and tuple(int(d) for d in dims.split(",") if d) in shapes]
+        assert moved == [], (name, moved)
+        assert "paged_cache_update" in text, name
