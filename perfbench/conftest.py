@@ -1,5 +1,5 @@
 """Fixtures for the benchmark's CPU tests: a copy of the benchmark with
-both configurations cut to a few thousand parameters, run on the CPU."""
+every configuration cut to a few thousand parameters, run on the CPU."""
 from __future__ import annotations
 
 import json
@@ -10,36 +10,26 @@ import pytest
 
 HERE = pathlib.Path(__file__).resolve().parent
 
-# Sizes of the tiny copies: the program's reduced configs, with the
-# benchmark's published-key names beside them.
-TINY = {
-    "qwen3-0.6b": dict(
-        program=dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
-                     d_ff=128, vocab_size=256, head_dim=16),
-        published=dict(hidden_size=64, intermediate_size=128,
-                       num_hidden_layers=2, num_attention_heads=4,
-                       num_key_value_heads=2, head_dim=16, vocab_size=256)),
-    "olmo-1b": dict(
-        program=dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
-                     d_ff=128, vocab_size=256, head_dim=16),
-        published=dict(hidden_size=64, intermediate_size=128,
-                       num_hidden_layers=2, num_attention_heads=4,
-                       num_key_value_heads=4, head_dim=16, vocab_size=256)),
-}
 
-# Widest logit gap each tiny copy may show, set between what bf16
-# serving reads against the float32 reference (seeds 1-3: qwen3 0.0029
-# at most, olmo 0.014) and what the control, the reference in fp8,
-# reads (qwen3 0.099 and more), on the CPU.
-TINY_LIMIT = {"qwen3-0.6b": 0.007, "olmo-1b": 0.025}
+def benchmark(root: pathlib.Path = HERE.parent) -> dict:
+    """``BENCHMARK.json`` under ``root``."""
+    return json.loads((root / "BENCHMARK.json").read_text())
 
 
-def tiny_config(name: str) -> dict:
-    c = json.loads((HERE / "configs" / f"{name}.json").read_text())
-    c.update(TINY[name]["published"])
-    c["program"]["overrides"].update(TINY[name]["program"])
+def tiny_config(name: str, root: pathlib.Path = HERE.parent) -> dict:
+    """Config ``name`` of the benchmark under ``root``, cut to the size
+    its file's ``tiny`` key gives: the program's reduced config, the
+    published-key sizes beside it, and the widest logit gap the tiny
+    copy may show, set between what bf16 serving reads against the
+    float32 reference and what the control, the reference in fp8,
+    reads on the CPU."""
+    entry = {c["name"]: c for c in benchmark(root)["configs"]}[name]
+    c = json.loads((root / entry["file"]).read_text())
+    tiny = c["tiny"]
+    c.update(tiny["published"])
+    c["program"]["overrides"].update(tiny["program"])
     c["serve"].update(slots=4, max_len=512, pool_pages=160)
-    c["correct"]["max_logit_gap"] = TINY_LIMIT[name]
+    c["correct"]["max_logit_gap"] = tiny["max_logit_gap"]
     return c
 
 
@@ -61,7 +51,7 @@ def tiny_mix(name: str) -> dict:
 def make_root(tmp: pathlib.Path) -> pathlib.Path:
     """A benchmark root at ``tmp``: this benchmark's files and
     ``BENCHMARK.json``, with tiny configurations and mixes."""
-    bench = json.loads((HERE.parent / "BENCHMARK.json").read_text())
+    bench = benchmark()
     dst = tmp / bench["paths"][0]
     shutil.copytree(HERE, dst, ignore=shutil.ignore_patterns(
         "__pycache__", "test_*.py", "conftest.py"))

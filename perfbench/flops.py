@@ -3,51 +3,19 @@
 Counted from the work each request needs, not from what a kernel or a
 step happens to compute: padding rows, passenger rows of a batched
 prefill chunk and pages read past a row's live length are not work.
-Multiply-adds count 2 operations; weights and cached keys and values
-are bfloat16 (2 bytes).
 
-``c`` is a benchmark config (published key names).
+``family`` is the config's module ``families/<reference>.py`` (the
+run's ``Run.family``), which counts the work of one token, one row of
+logits and one attention call; ``c`` is a benchmark config (published
+key names).  This module sums those counts over a request's steps.
 """
 from __future__ import annotations
 
 from typing import Dict
 
-KV_BYTES = 2
 
-
-def matmul_flops_per_token(c: Dict) -> float:
-    """Projection and MLP operations of one token through every block."""
-    d, hd = c["hidden_size"], c["head_dim"]
-    h, kvh, ff = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["intermediate_size"])
-    per_layer = d * h * hd + 2 * d * kvh * hd + h * hd * d + 3 * d * ff
-    return 2.0 * c["num_hidden_layers"] * per_layer
-
-
-def head_flops(c: Dict) -> float:
-    """Logits of one position over the whole vocabulary."""
-    return 2.0 * c["hidden_size"] * c["vocab_size"]
-
-
-def attn_flops(c: Dict, ctx: float) -> float:
-    """Attention of one query over ``ctx`` keys, every layer: q.k and
-    p.v, each ``heads * head_dim`` multiply-adds per key."""
-    return (4.0 * c["num_hidden_layers"] * c["num_attention_heads"]
-            * c["head_dim"] * ctx)
-
-
-def attn_bytes(c: Dict, ctx: float, queries: int = 1) -> float:
-    """Bytes an attention call must move for ``queries`` consecutive
-    queries over ``ctx`` keys, every layer: the keys and values once,
-    the queries read and the outputs written once."""
-    L, kvh, h, hd = (c["num_hidden_layers"], c["num_key_value_heads"],
-                     c["num_attention_heads"], c["head_dim"])
-    kv = 2 * kvh * hd * ctx * KV_BYTES
-    qo = 2 * h * hd * queries * KV_BYTES
-    return float(L * (kv + qo))
-
-
-def decode_work(c: Dict, prompt_len: int, served: int) -> Dict[str, float]:
+def decode_work(family, c: Dict, prompt_len: int,
+                served: int) -> Dict[str, float]:
     """Work of the decode steps of one request that served ``served``
     tokens after a prompt of ``prompt_len``: the first token comes from
     prefill; decode step ``i`` (1-based) feeds token ``i`` at position
@@ -55,14 +23,14 @@ def decode_work(c: Dict, prompt_len: int, served: int) -> Dict[str, float]:
     steps = max(0, served - 1)
     # sum of contexts prompt_len + i for i = 1..steps
     ctx_sum = steps * prompt_len + steps * (steps + 1) / 2
-    per_tok = matmul_flops_per_token(c) + head_flops(c)
-    return {"flops": steps * per_tok + attn_flops(c, ctx_sum),
-            "attn_flops": attn_flops(c, ctx_sum),
-            "attn_bytes": attn_bytes(c, ctx_sum, queries=steps),
+    per_tok = family.matmul_flops_per_token(c) + family.head_flops(c)
+    return {"flops": steps * per_tok + family.attn_flops(c, ctx_sum),
+            "attn_flops": family.attn_flops(c, ctx_sum),
+            "attn_bytes": family.attn_bytes(c, ctx_sum, queries=steps),
             "tokens": steps}
 
 
-def prefill_work(c: Dict, prompt_len: int, cached: int,
+def prefill_work(family, c: Dict, prompt_len: int, cached: int,
                  chunk: int) -> Dict[str, float]:
     """Work of prefilling positions ``cached .. prompt_len - 1`` (the
     first ``cached`` came from the prefix cache) in chunks of
@@ -79,9 +47,9 @@ def prefill_work(c: Dict, prompt_len: int, cached: int,
     start = cached
     while start < prompt_len:
         end = min(start + chunk, prompt_len)
-        bytes_ += attn_bytes(c, end, queries=end - start)
+        bytes_ += family.attn_bytes(c, end, queries=end - start)
         start = end
-    return {"flops": n * matmul_flops_per_token(c) + head_flops(c)
-            + attn_flops(c, ctx_sum),
-            "attn_flops": attn_flops(c, ctx_sum),
+    return {"flops": n * family.matmul_flops_per_token(c)
+            + family.head_flops(c) + family.attn_flops(c, ctx_sum),
+            "attn_flops": family.attn_flops(c, ctx_sum),
             "attn_bytes": bytes_, "tokens": n}

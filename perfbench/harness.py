@@ -5,8 +5,9 @@ A cell (``BENCHMARK.json``'s ``workloads``) is a configuration
 A run:
 
 1. makes the weights on the device from the seed, in bfloat16, in one
-   jitted call (``reference/<family>.py`` draws them; ``program.layout``
-   puts them into the program's tree);
+   jitted call (``reference/<reference>.py`` draws them; the config's
+   family, ``families/<reference>.py``, puts them into the program's
+   tree);
 2. builds the engine as operators run it, with a ``pmt.Session`` on the
    modelled ``tpu`` backend, and warms up every program the window uses:
    one prefill chunk and one decode step at the cell's batch, and each
@@ -80,6 +81,7 @@ class Run:
     """What a reader of a per-layer metric may read."""
 
     config: Dict
+    family: Any                     # families/<reference>.py: work counts
     chunk: int
     t0: float
     window_s: float
@@ -149,8 +151,14 @@ def reader(bench_dir: pathlib.Path, name: str) -> Callable:
 
 
 def reference(bench_dir: pathlib.Path, config: Dict):
-    """The plain reference ``reference/<family>.py`` of a config."""
+    """The plain reference ``reference/<reference>.py`` of a config."""
     return load_module(bench_dir / "reference" / f"{config['reference']}.py")
+
+
+def family(bench_dir: pathlib.Path, config: Dict):
+    """The family ``families/<reference>.py`` of a config: the sizes its
+    program config states, the program's tree, the work it counts."""
+    return load_module(bench_dir / "families" / f"{config['reference']}.py")
 
 
 # -- statistics ------------------------------------------------------------
@@ -330,14 +338,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if compile_cache:
         enable_compile_cache()
     counter = CompileCounter.get()
-    mcfg = program.model_config(config)
+    fam = family(bench_dir, config)
+    mcfg = program.model_config(config, fam)
     eng_cfg = {**config["serve"], **mix.get("engine", {})}
     ref = reference(bench_dir, config)
     backlog = mix["kind"] == "backlog"
 
     key = ref.key_from_seed(seed)
     t_weights = time.monotonic()
-    params = jax.jit(lambda k: program.layout(config, ref.make_weights(
+    params = jax.jit(lambda k: fam.layout(config, ref.make_weights(
         config, k)))(key)
     jax.block_until_ready(params)
     program.check_layout(mcfg, params)
@@ -397,7 +406,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in used)
     window_s = (t_end - t0) if backlog else seconds
-    run = Run(config=config, chunk=chunk, t0=t0,
+    run = Run(config=config, family=fam, chunk=chunk, t0=t0,
               window_s=window_s, requests=in_window,
               lead_in=[sv for s, sv in zip(specs, served) if s.due_s < 0],
               stats0=stats0,

@@ -14,7 +14,8 @@ def read(run):
         return None
     kernel = program.KERNELS["decode_attention"]
     t = xplane.op_time_s(run.trace).get(kernel, 0.0)
-    w = [flops.decode_work(run.config, r.prompt_len, r.served)
+    w = [flops.decode_work(run.family, run.config, r.prompt_len,
+                           r.served)
          for r in run.all_requests if r.served]
     f = sum(x["attn_flops"] for x in w)
     b = sum(x["attn_bytes"] for x in w)

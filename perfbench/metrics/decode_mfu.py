@@ -10,7 +10,8 @@ def read(run):
     if run.trace is None or not run.peaks:
         return None
     t = xplane.module_time_s(run.trace, "decode")
-    work = sum(flops.decode_work(run.config, r.prompt_len, r.served)["flops"]
+    work = sum(flops.decode_work(run.family, run.config, r.prompt_len,
+                                 r.served)["flops"]
                for r in run.all_requests if r.served)
     if t <= 0 or work <= 0:
         return None

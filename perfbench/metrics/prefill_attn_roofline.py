@@ -12,7 +12,8 @@ def read(run):
         return None
     kernel = program.KERNELS["prefill_attention"]
     t = xplane.op_time_s(run.trace).get(kernel, 0.0)
-    w = [flops.prefill_work(run.config, r.prompt_len, r.cached, run.chunk)
+    w = [flops.prefill_work(run.family, run.config, r.prompt_len, r.cached,
+                            run.chunk)
          for r in run.all_requests if r.first is not None]
     f = sum(x["attn_flops"] for x in w)
     b = sum(x["attn_bytes"] for x in w)

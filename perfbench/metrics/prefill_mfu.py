@@ -11,8 +11,8 @@ def read(run):
     if run.trace is None or not run.peaks:
         return None
     t = xplane.module_time_s(run.trace, "prefill_chunk")
-    work = sum(flops.prefill_work(run.config, r.prompt_len, r.cached,
-                                  run.chunk)["flops"]
+    work = sum(flops.prefill_work(run.family, run.config, r.prompt_len,
+                                  r.cached, run.chunk)["flops"]
                for r in run.all_requests if r.first is not None)
     if t <= 0 or work <= 0:
         return None

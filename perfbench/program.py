@@ -22,8 +22,10 @@ and counters, and the names its kernels carry in a device trace:
   actually prefilled) and ``serve/req<N>/decode``, on the monotonic
   clock.
 
-The weights are made here, by the benchmark, from the seed; this module
-only lays them out as the program's tree.
+The weights are made by the benchmark, from the seed.  What differs
+from one model family to another (the sizes the program's config has
+to state, the program's parameter tree, the work a token needs) lives
+in ``families/<reference>.py``, one file a family.
 """
 from __future__ import annotations
 
@@ -76,48 +78,17 @@ KERNELS = {"decode_attention": "paged_decode_attention",
            "cache_update": "paged_cache_update"}
 
 
-def model_config(c: Dict):
+def model_config(c: Dict, family):
     """The program's config for benchmark config ``c``, checked against
-    the published sizes the file states."""
+    the published sizes the file states (``family.stated``)."""
     prog = c["program"]
     cfg = registry.get_config(prog["registry"], **prog.get("overrides", {}))
-    want = {"num_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-            "num_heads": c["num_attention_heads"],
-            "num_kv_heads": c["num_key_value_heads"],
-            "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-            "vocab_size": c["vocab_size"], "rope_theta": c["rope_theta"],
-            "qk_norm": c["qk_norm"], "tie_embeddings": True,
-            "norm_type": ("rmsnorm" if c["norm"] == "rmsnorm"
-                          else "layernorm_nonparam")}
+    want = family.stated(c)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"{prog['registry']}: the program's config "
                          f"{got} is not the one stated {want}")
     return cfg
-
-
-def layout(c: Dict, w: Dict) -> Dict:
-    """Benchmark weights ``w`` (``reference.dense`` names) as the
-    program's parameter tree.  Call under ``jax.jit``."""
-    L, d = c["num_hidden_layers"], c["hidden_size"]
-    h, kvh, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["head_dim"])
-    mixer = {"wq": w["wq"].reshape(L, d, h, hd),
-             "wk": w["wk"].reshape(L, d, kvh, hd),
-             "wv": w["wv"].reshape(L, d, kvh, hd),
-             "wo": w["wo"].reshape(L, h, hd, d)}
-    if c["qk_norm"]:
-        mixer.update(q_norm=w["q_norm"], k_norm=w["k_norm"])
-    norm = (lambda n: {"scale": w[n]}) if c["norm"] == "rmsnorm" \
-        else (lambda n: {})
-    return {"embed": {"embedding": w["embed"]},
-            "final_norm": norm("final_norm"),
-            "units": {"r0": {"norm_1": norm("attn_norm"),
-                             "norm_2": norm("mlp_norm"),
-                             "mixer": mixer,
-                             "ffn": {"w_gate": w["w_gate"],
-                                     "w_up": w["w_up"],
-                                     "w_down": w["w_down"]}}}}
 
 
 def check_layout(cfg, params) -> None:

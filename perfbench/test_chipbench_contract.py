@@ -187,7 +187,9 @@ def test_tpot_reads_token_times_not_the_cut():
                                                 (5, 1.4)])
     one = harness.Served(rid=2, due=0.0, prompt_len=8, served=1,
                          first=1.0, times=[(1, 1.0)])
-    run = harness.Run(config={}, chunk=32, t0=0.0, window_s=2.0,
+    run = harness.Run(config={},
+                      family=harness.family(HERE, {"reference": "dense"}),
+                      chunk=32, t0=0.0, window_s=2.0,
                       requests=[cut_late, finished, one], lead_in=[],
                       stats0={}, stats1={}, stall_events=[], peaks={})
     assert harness.end_to_end("tpot_p90_ms", run) == pytest.approx(100.0)

@@ -5,7 +5,7 @@ configuration's bf16) in the program's place."""
 import jax
 import pytest
 
-from conftest import run_tiny
+from conftest import benchmark, run_tiny
 
 
 def _altered_token(engine):
@@ -54,7 +54,8 @@ def test_broken_timed_path_is_not_correct(tiny_root, cell, fault):
     assert gap["value"] > gap["limit"]
 
 
-@pytest.mark.parametrize("cell", ["qwen3-0.6b.chat", "olmo-1b.batch"])
+@pytest.mark.parametrize("cell",
+                         [w["name"] for w in benchmark()["workloads"]])
 def test_fp8_reference_control_is_not_correct(tiny_root, cell):
     """The control: the reference in fp8 in the program's place, judged
     on the same prompts and served tokens by the same comparison, reads

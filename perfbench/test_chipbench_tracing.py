@@ -18,7 +18,9 @@ def _read(name, run):
 
 
 def _run(trace=None, requests=(), stats0=None, stats1=None, window=1e-6):
-    return harness.Run(config={}, chunk=32, t0=0.0, window_s=1.0,
+    return harness.Run(config={},
+                       family=harness.family(HERE, {"reference": "dense"}),
+                       chunk=32, t0=0.0, window_s=1.0,
                        requests=list(requests), lead_in=[],
                        stats0=stats0 or {}, stats1=stats1 or {},
                        stall_events=[], peaks={}, trace=trace,
