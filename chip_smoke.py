@@ -195,7 +195,7 @@ def first_token_logits(cfg, params, prompts, layout: str, *,
                 jnp.full((n,), c * t, jnp.int32), jnp.asarray(last), table)
         if compiled is None:
             compiled = step.lower(*args).compile()
-        logits, caches = compiled(*args)
+        logits, caches, _ = compiled(*args)
         for i, k in enumerate(n_chunks):
             if c == k - 1:
                 out[i] = np.asarray(logits[i], np.float32)

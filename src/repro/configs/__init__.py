@@ -1,7 +1,9 @@
 """Architecture registry: ``--arch <id>`` resolution + dry-run input specs.
 
 ``get_config(name)`` returns the full published config;
-``get_config(name, reduced=True)`` the reduced smoke-test variant.
+``get_config(name, reduced=True)`` the reduced smoke-test variant;
+further keyword arguments replace fields, a dict those of a nested
+config (``moe={"held_experts": 8}``).
 ``input_specs(cfg, shape)`` returns ShapeDtypeStruct stand-ins for every
 model input of that (arch x shape) cell — weak-type-correct, shardable,
 no device allocation.  Decode shapes also need cache specs, built with
@@ -41,6 +43,10 @@ def get_config(name: str, reduced: bool = False, **overrides) -> ModelConfig:
     cfg = mod.REDUCED if reduced else mod.CONFIG
     if overrides:
         import dataclasses
+        # a dict overrides fields of a nested config (moe, mla, ...)
+        overrides = {k: dataclasses.replace(getattr(cfg, k), **v)
+                     if isinstance(v, dict) else v
+                     for k, v in overrides.items()}
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg
 

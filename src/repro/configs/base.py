@@ -14,18 +14,74 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts settings (token-dropping, sort-based dispatch)."""
+    """Mixture-of-experts settings.
+
+    Routing: ``scoring_func`` "softmax" (top-k of the softmax) or
+    "sigmoid" with ``topk_method`` "noaux_tc" (DeepSeek-V3): a
+    per-expert correction bias, used for choosing only, ranks
+    ``n_group`` groups of experts by the sum of their two best biased
+    scores, keeps the best ``topk_group`` groups and takes the top-k
+    experts inside them.  The chosen gates (unbiased scores) are
+    normalized to sum to one when ``norm_topk_prob``, then scaled by
+    ``routed_scaling_factor``.
+
+    Expert parallelism: the layer holds ``held_experts`` experts,
+    ``[first_held_expert, first_held_expert + held_experts)`` of the
+    ``num_experts`` the router scores (0 = all of them); it computes
+    the part of the result that its own experts give, and assignments
+    to the others add nothing here.
+    """
 
     num_experts: int
     top_k: int
     ff_dim: int                      # per-expert intermediate size
     num_shared_experts: int = 0      # deepseek-style always-on experts
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training dispatch only (see moe.py)
     first_dense_layers: int = 0      # leading layers that stay dense
     dense_ff_dim: int = 0            # ffn size of those dense layers
     every_k_layers: int = 1          # jamba: MoE on every k-th layer only
     moe_layer_offset: int = 0        # jamba: first MoE layer index
     router_aux_loss: float = 0.001   # load-balance loss weight
+    scoring_func: str = "softmax"    # softmax | sigmoid
+    topk_method: str = "greedy"      # greedy | noaux_tc
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    held_experts: int = 0            # 0 -> all num_experts
+    first_held_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts held (and computed) by this layer."""
+        return self.held_experts or self.num_experts
+
+    def __post_init__(self):
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring_func {self.scoring_func!r}")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.num_experts % self.n_group or \
+                not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(f"{self.num_experts} experts in {self.n_group}"
+                             f" groups, {self.topk_group} kept")
+        if self.first_held_expert + self.held > self.num_experts:
+            raise ValueError(f"held experts [{self.first_held_expert}, "
+                             f"{self.first_held_expert + self.held}) "
+                             f"outside {self.num_experts}")
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V3 states it
+    (``rope_scaling`` with ``type`` "yarn")."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +139,7 @@ class ModelConfig:
     sliding_window: Optional[int] = None    # gemma2 local layers: 4096
     layer_pattern: Optional[str] = None     # e.g. "LG" local/global repeat
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None   # deepseek-v3: YaRN
     # positional scheme: "rope" | "sinusoidal" (whisper) | "none" (jamba)
     pos_embed: str = "rope"
     m_rope: bool = False             # qwen2-vl 3-section rope

@@ -72,6 +72,21 @@ def check_block(bk: int, size: int, what: str) -> None:
             f"need one or the other (pick a block_k / size that 8 divides)")
 
 
+def kv_rows(ref, h: int):
+    """Kv head ``h``'s (rows, hd) of a K/V block: ``(1, rows, KVH, hd)``,
+    or ``(1, rows, hd)`` from a pool stored without a head axis (one kv
+    head: the MLA latent pool)."""
+    return ref[0, :, h, :] if len(ref.shape) == 4 else ref[0]
+
+
+def kv_block(ps: int, kvh: int, width: int, headless: bool):
+    """Block shape and index-map tail of one page of a paged pool:
+    every kv head of the page, or the page of a headless pool."""
+    if headless:
+        return (1, ps, width), (0, 0)
+    return (1, ps, kvh, width), (0, 0, 0)
+
+
 def unpack(refs, quantized: bool):
     """Split a kernel's trailing refs into (k scale, v scale, o, m, l,
     acc): quantized call sites append two float32 scale operands."""
@@ -86,7 +101,7 @@ def _fold_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
     accumulator.  ``valid``: (G, bk) bool, shared by all heads."""
     for h in range(q_ref.shape[1]):
         q = q_ref[0, h].astype(jnp.float32) * scale            # (G, hdq)
-        k = k_ref[0, :, h, :].astype(jnp.float32)              # (bk, hdq)
+        k = kv_rows(k_ref, h).astype(jnp.float32)              # (bk, hdq)
         if ks_ref is not None:
             k = k * ks_ref[0, :, h:h + 1].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -101,7 +116,7 @@ def _fold_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, :, h, :].astype(jnp.float32)              # (bk, hdv)
+        v = kv_rows(v_ref, h).astype(jnp.float32)              # (bk, hdv)
         if vs_ref is not None:
             v = v * vs_ref[0, :, h:h + 1].astype(jnp.float32)
         acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
@@ -214,7 +229,9 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, layer, page_table, lens,
                                   interpret: bool = False):
     """Paged flash-decode: q (B, KVH, G, hdq) against the stacked
     physical page pools k_pool/v_pool (L, P, page_size, KVH, hd*) of a
-    layer stack, at layer ``layer`` (int32 scalar, may be traced),
+    layer stack, or (L, P, page_size, hd*) for one kv head stored
+    without a head axis (read in place, as stored), at layer ``layer``
+    (int32 scalar, may be traced),
     through a page_table (B, NB) int32.  lens: (B,) int32 new-token
     positions.  The stack is seen as one pool of ``L * P`` pages (a free
     reshape, so it is read in place, never sliced) through the page
@@ -255,8 +272,12 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, layer, page_table, lens,
             j = jnp.maximum(j, jnp.minimum(first, last))
         return pt[bi, j]
 
+    headless = k_pool.ndim == 4
+    k_blk, tail = kv_block(ps, kvh, hdq, headless)
+    v_blk, _ = kv_block(ps, kvh, hdv, headless)
+
     def kv_map(bi, ki, lens, pt):
-        return (_page(bi, ki, lens, pt), 0, 0, 0)
+        return (_page(bi, ki, lens, pt),) + tail
 
     def scale_map(bi, ki, lens, pt):
         # Same physical page as the codes: the scale DMA is elided for
@@ -265,8 +286,8 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, layer, page_table, lens,
 
     in_specs = [
         pl.BlockSpec((1, kvh, g, hdq), q_map),
-        pl.BlockSpec((1, ps, kvh, hdq), kv_map),
-        pl.BlockSpec((1, ps, kvh, hdv), kv_map),
+        pl.BlockSpec(k_blk, kv_map),
+        pl.BlockSpec(v_blk, kv_map),
     ]
     operands = [q, flat(k_pool), flat(v_pool)]
     if quantized:

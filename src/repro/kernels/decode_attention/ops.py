@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from repro.kernels.constants import NEG_INF
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_paged_pallas, decode_attention_pallas)
-from repro.kernels.decode_attention.ref import take_pages
+from repro.kernels.decode_attention.ref import pool_heads, take_pages
 
 
 def _resolve(impl: str) -> str:
@@ -269,7 +269,9 @@ def decode_attention_paged(q, k_pool, v_pool, layer, page_table, cur_len, *,
 
     q: (B, 1, H, hdq) new-token queries.  k_pool/v_pool:
     (L, P, page_size, KVH, hd*) a layer stack's physical pages shared by
-    all rows, *after* the new token's k/v landed (``paged_cache_update``);
+    all rows, or (L, P, page_size, hd*) for one kv head stored without
+    a head axis (the MLA latent pool, read in place), *after* the new
+    token's k/v landed (``paged_cache_update``);
     layer ``layer`` (int32 scalar, may be traced) is read in place.
     page_table: (B, NB) int32 logical block -> physical page.  cur_len:
     (B,) int32.  Paged caches store sliding-window layers unwrapped, so
@@ -284,7 +286,7 @@ def decode_attention_paged(q, k_pool, v_pool, layer, page_table, cur_len, *,
     if sq != 1:
         raise ValueError(f"decode_attention_paged takes one query token, "
                          f"got Sq={sq}")
-    kvh = k_pool.shape[3]
+    kvh = pool_heads(k_pool)
     if h % kvh:
         raise ValueError(f"H={h} not divisible by KVH={kvh}")
     g = h // kvh

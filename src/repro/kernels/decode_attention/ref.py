@@ -50,6 +50,12 @@ def pick_block_k(cache_size: int, block_k: int) -> int:
     return math.gcd(min(block_k, cache_size), cache_size)
 
 
+def pool_heads(pool) -> int:
+    """Kv heads of a stacked paged pool: (L, P, page_size, KVH, hd), or
+    1 for a pool stored without a head axis, (L, P, page_size, hd)."""
+    return pool.shape[3] if pool.ndim == 5 else 1
+
+
 def take_pages(pool, layer, pages):
     """Pages ``pages`` (int32, any shape) of layer ``layer`` of a stacked
     (L, P, page_size, ...) pool, gathered from the whole stack: the

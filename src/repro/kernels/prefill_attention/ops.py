@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.constants import DEFAULT_BLOCK_K, NEG_INF
-from repro.kernels.decode_attention.ref import take_pages
+from repro.kernels.decode_attention.ref import pool_heads, take_pages
 from repro.kernels.prefill_attention.prefill_attention import (
     prefill_attention_paged_pallas, prefill_attention_pallas)
 
@@ -165,7 +165,8 @@ def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, layer,
     q: (B, T, H, hdq) chunk queries at positions ``offset[b] + i``.
     k_chunk/v_chunk: (B, T, KVH, *) — the chunk's own keys/values (NOT
     yet scattered into the pool).  k_pool/v_pool: (L, P, page_size, KVH,
-    *) a layer stack's physical pages; layer ``layer`` (int32 scalar,
+    *) a layer stack's physical pages, or (L, P, page_size, *) for one
+    kv head stored without a head axis; layer ``layer`` (int32 scalar,
     may be traced) holds positions ``< offset[b]`` of every row,
     addressed through page_table (B, NB) int32.  offset: scalar or (B,)
     int32.  Paged caches store sliding-window layers unwrapped, so
@@ -180,7 +181,7 @@ def prefill_attention_paged(q, k_chunk, v_chunk, k_pool, v_pool, layer,
     if k_chunk.shape[1] != t:
         raise ValueError(f"chunk keys cover {k_chunk.shape[1]} tokens but "
                          f"the query chunk has {t}")
-    kvh = k_pool.shape[3]
+    kvh = pool_heads(k_pool)
     if h % kvh:
         raise ValueError(f"H={h} not divisible by KVH={kvh}")
     g = h // kvh

@@ -53,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.constants import DEFAULT_BLOCK_K, NEG_INF
 from repro.kernels.decode_attention.decode_attention import (
-    check_block, finish, init, scratch, unpack)
+    check_block, finish, init, kv_block, kv_rows, scratch, unpack)
 from repro.kernels.prefill_attention.ref import pick_block_k
 
 
@@ -65,8 +65,8 @@ def _fold_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
     block's (bk, KVH) scales when it holds quantized codes."""
     for h in range(q_ref.shape[1]):
         q = q_ref[0, h].astype(jnp.float32) * scale            # (T, G, hdq)
-        k_blk = k_ref[0, :, h, :]
-        v_blk = v_ref[0, :, h, :]
+        k_blk = kv_rows(k_ref, h)
+        v_blk = kv_rows(v_ref, h)
         if ks_ref is not None:
             k_blk = k_blk.astype(jnp.float32) * \
                 ks_ref[0, :, h:h + 1].astype(jnp.float32)
@@ -93,10 +93,10 @@ def _fold_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
 
 def _chunk_phase(q_ref, kx_ref, vx_ref, m_ref, l_ref, acc_ref, ki, *,
                  cache_steps: int, bk_t: int, chunk: int, window, scale,
-                 softcap):
-    """Fold the chunk's own keys (causal; every block holds a key some
-    query attends, so none are skippable)."""
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
+                 softcap, q_lo=0):
+    """Fold the chunk's own keys (causal) into the ``chunk`` queries
+    from chunk position ``q_lo`` on."""
+    q_idx = q_lo + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
     j_lo = (ki - cache_steps) * bk_t
     cols = j_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk_t), 2)
     diff = q_idx - cols                                        # (T, 1, bk_t)
@@ -155,11 +155,12 @@ def _prefill_kernel(offs_ref, q_ref, kx_ref, vx_ref, kc_ref, vc_ref, *refs,
 def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
                           vc_ref, *refs, scale: float, window, softcap,
                           ps: int, bk_t: int, cache_steps: int,
-                          total_steps: int, chunk: int,
+                          total_steps: int, tq: int,
                           quantized: bool = False):
     kcs_ref, vcs_ref, o_ref, m_ref, l_ref, acc_ref = unpack(refs, quantized)
     bi = pl.program_id(0)
-    ki = pl.program_id(1)
+    q_lo = pl.program_id(1) * tq          # this block's first query
+    ki = pl.program_id(2)
     off = offs_ref[bi]
 
     @pl.when(ki == 0)
@@ -173,13 +174,14 @@ def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
     k_lo = ki * ps
     live = (ki < cache_steps) & (k_lo < off)
     if window is not None:
-        live &= (k_lo + ps - 1) >= off - (window - 1)
+        live &= (k_lo + ps - 1) >= off + q_lo - (window - 1)
 
     @pl.when(live)
     def _cache_phase():
         cols = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
-        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-        valid = jnp.broadcast_to(cols < off, (chunk, 1, ps))
+        q_pos = off + q_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, 1, 1), 0)
+        valid = jnp.broadcast_to(cols < off, (tq, 1, ps))
         if window is not None:
             valid &= (q_pos - cols) < window
         _fold_heads(q_ref, kc_ref, vc_ref, kcs_ref, vcs_ref, m_ref, l_ref,
@@ -190,12 +192,30 @@ def _paged_prefill_kernel(offs_ref, pt_ref, q_ref, kx_ref, vx_ref, kc_ref,
     @pl.when(ki >= cache_steps)
     def _():
         _chunk_phase(q_ref, kx_ref, vx_ref, m_ref, l_ref, acc_ref, ki,
-                     cache_steps=cache_steps, bk_t=bk_t, chunk=chunk,
-                     window=window, scale=scale, softcap=softcap)
+                     cache_steps=cache_steps, bk_t=bk_t, chunk=tq,
+                     window=window, scale=scale, softcap=softcap, q_lo=q_lo)
 
     @pl.when(ki == total_steps - 1)
     def _():
         finish(o_ref, l_ref, acc_ref)
+
+
+# Query rows (block queries x heads of a kv head) times their key and
+# value widths that one grid step of the paged kernel holds: the q and
+# out blocks, double-buffered, and the float32 accumulators scale with
+# it.  Up to it a chunk is one query block (every dense shape); MLA's
+# 128 heads over a 576 + 512 wide latent take blocks of 8 queries.
+QUERY_BLOCK_ELEMS = 1_200_000
+
+
+def query_block(t: int, kvh: int, g: int, hdq: int, hdv: int) -> int:
+    """Queries of a chunk of ``t`` that one grid step takes: the largest
+    divisor of ``t`` whose rows stay within ``QUERY_BLOCK_ELEMS``."""
+    tq = t
+    while tq > 1 and (t % tq or tq * kvh * g * (hdq + hdv)
+                      > QUERY_BLOCK_ELEMS):
+        tq -= 1
+    return tq
 
 
 def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
@@ -205,17 +225,22 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
                                    interpret: bool = False):
     """Paged chunked-prefill: q (B, KVH, T, G, hdq); chunk k/v
     (B, T, KVH, *); the stacked physical pools (L, P, page_size, KVH, *)
-    of a layer stack, read at layer ``layer`` (int32 scalar, may be
-    traced) through page_table (B, NB) int32; offs (B,) int32.  The
-    stack is seen as one pool of ``L * P`` pages (a free reshape, so it
-    is read in place) through the page table offset by ``layer * P``.
-    The cache-phase BlockSpec index maps read the page table from
-    scalar-prefetch SMEM (one block == one page, all kv heads) with the
-    same clamp-to-elide-DMA trick as the contiguous kernel.  Paged caches are unwrapped:
-    sliding windows arrive as the explicit ``window`` mask, never
-    ``ring``.  ``k_scale``/``v_scale``: (L, P, page_size, KVH) float32
-    per-row scale pools when the code pools are quantized (chunk k/v
-    stay full precision).  Returns (B, KVH, T, G, hdv) in q.dtype."""
+    of a layer stack, or (L, P, page_size, *) for one kv head stored
+    without a head axis (read in place, as stored), read at layer
+    ``layer`` (int32 scalar, may be traced) through page_table (B, NB)
+    int32; offs (B,) int32.  The stack is seen as one pool of ``L * P``
+    pages (a free reshape, so it is read in place) through the page
+    table offset by ``layer * P``.  The cache-phase BlockSpec index maps
+    read the page table from scalar-prefetch SMEM (one block == one
+    page, all kv heads) with the same clamp-to-elide-DMA trick as the
+    contiguous kernel.  The grid walks the chunk's queries in blocks of
+    ``query_block`` (the whole chunk unless its rows would overflow the
+    scoped VMEM), each block sweeping the prefix and the chunk.  Paged
+    caches are unwrapped: sliding windows arrive as the explicit
+    ``window`` mask, never ``ring``.  ``k_scale``/``v_scale``: (L, P,
+    page_size, KVH) float32 per-row scale pools when the code pools are
+    quantized (chunk k/v stay full precision).  Returns (B, KVH, T, G,
+    hdv) in q.dtype."""
     b, kvh, t, g, hdq = q.shape
     p, ps = k_pool.shape[1:3]
     nb = page_table.shape[1]
@@ -224,6 +249,7 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     bk_t = pick_block_k(t, ps)       # match the paged ref twin's blocking
     if not interpret:
         check_block(bk_t, t, "paged prefill_attention chunk blocks")
+    tq = query_block(t, kvh, g, hdq, hdv)
     cache_steps = nb
     chunk_steps = t // bk_t
     total_steps = cache_steps + chunk_steps
@@ -234,10 +260,10 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     page_table = page_table.astype(jnp.int32) + \
         jnp.asarray(layer, jnp.int32) * p
 
-    def q_map(bi, ki, offs, pt):
-        return (bi, 0, 0, 0, 0)
+    def q_map(bi, qi, ki, offs, pt):
+        return (bi, 0, qi, 0, 0)
 
-    def _page(bi, ki, offs, pt):
+    def _page(bi, qi, ki, offs, pt):
         # Clamp to the row's needed page range, then go through the
         # page table: revisited physical indices elide the HBM copy
         # (beyond-prefix pages, the whole chunk phase, and — windowed —
@@ -245,27 +271,31 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
         last = jnp.minimum(jnp.maximum(offs[bi] - 1, 0), c - 1) // ps
         j = jnp.minimum(ki, last)
         if window is not None:
-            first = jnp.maximum(offs[bi] - (window - 1), 0) // ps
+            first = jnp.maximum(offs[bi] + qi * tq - (window - 1), 0) // ps
             j = jnp.maximum(j, jnp.minimum(first, last))
         return pt[bi, j]
 
-    def cache_map(bi, ki, offs, pt):
-        return (_page(bi, ki, offs, pt), 0, 0, 0)
+    headless = k_pool.ndim == 4
+    kc_blk, tail = kv_block(ps, kvh, hdq, headless)
+    vc_blk, _ = kv_block(ps, kvh, hdv, headless)
 
-    def scale_map(bi, ki, offs, pt):
+    def cache_map(bi, qi, ki, offs, pt):
+        return (_page(bi, qi, ki, offs, pt),) + tail
+
+    def scale_map(bi, qi, ki, offs, pt):
         # Same physical page as the codes: scale DMAs elide together.
-        return (_page(bi, ki, offs, pt), 0, 0)
+        return (_page(bi, qi, ki, offs, pt), 0, 0)
 
-    def chunk_map(bi, ki, offs, pt):
+    def chunk_map(bi, qi, ki, offs, pt):
         j = jnp.clip(ki - cache_steps, 0, chunk_steps - 1)
         return (bi, j, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, kvh, t, g, hdq), q_map),
+        pl.BlockSpec((1, kvh, tq, g, hdq), q_map),
         pl.BlockSpec((1, bk_t, kvh, hdq), chunk_map),
         pl.BlockSpec((1, bk_t, kvh, hdv), chunk_map),
-        pl.BlockSpec((1, ps, kvh, hdq), cache_map),
-        pl.BlockSpec((1, ps, kvh, hdv), cache_map),
+        pl.BlockSpec(kc_blk, cache_map),
+        pl.BlockSpec(vc_blk, cache_map),
     ]
     operands = [q, k_chunk, v_chunk, flat(k_pool), flat(v_pool)]
     if quantized:
@@ -276,20 +306,20 @@ def prefill_attention_paged_pallas(q, k_chunk, v_chunk, k_pool, v_pool,
     kernel = functools.partial(
         _paged_prefill_kernel, scale=scale, window=window, softcap=softcap,
         ps=ps, bk_t=bk_t, cache_steps=cache_steps, total_steps=total_steps,
-        chunk=t, quantized=quantized)
+        tq=tq, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, total_steps),
+        grid=(b, t // tq, total_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, t, g, hdv), q_map),
-        scratch_shapes=scratch((kvh, t, g), hdv),
+        out_specs=pl.BlockSpec((1, kvh, tq, g, hdv), q_map),
+        scratch_shapes=scratch((kvh, tq, g), hdv),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t, g, hdv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_prefill_attention",
     )(offs.astype(jnp.int32), page_table, *operands)

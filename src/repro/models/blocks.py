@@ -176,7 +176,9 @@ def block_forward(cfg: ModelConfig, p, x, positions, idx: int, *,
         x = x + attn.cross_attention(cfg, p["cross"], h, enc_out)
 
     h = layers.apply_norm(cfg, p["norm_2"], x)
-    if "router" in p["ffn"]:
+    if "router" in p["ffn"] and collect_kv:
+        out, _ = moe.moe_serve(cfg, p["ffn"], h)    # serve prefill
+    elif "router" in p["ffn"]:
         out, aux = moe.apply_moe(cfg, p["ffn"], h)
     else:
         out = layers.apply_mlp(cfg, p["ffn"], h)
@@ -274,7 +276,7 @@ def block_decode(cfg: ModelConfig, p, x, cache, cur_len, idx: int):
 
     h = layers.apply_norm(cfg, p["norm_2"], x)
     if "router" in p["ffn"]:
-        out, _ = moe.apply_moe(cfg, p["ffn"], h)
+        out, _ = moe.moe_serve(cfg, p["ffn"], h)
     else:
         out = layers.apply_mlp(cfg, p["ffn"], h)
     if cfg.post_block_norm:
@@ -362,7 +364,7 @@ def block_prefill_chunk(cfg: ModelConfig, p, x, cache, offset, valid_len,
 
     h = layers.apply_norm(cfg, p["norm_2"], x)
     if "router" in p["ffn"]:
-        out, _ = moe.apply_moe(cfg, p["ffn"], h)
+        out, _ = moe.moe_serve(cfg, p["ffn"], h)
     else:
         out = layers.apply_mlp(cfg, p["ffn"], h)
     if cfg.post_block_norm:
@@ -405,26 +407,32 @@ def paged_cache_axes(cfg: ModelConfig, idx: int):
     return axes
 
 
-def _block_tail(cfg: ModelConfig, p, x, out):
-    """Shared post-mixer tail: post-norm, residual, FFN (dense or MoE)."""
+def _block_tail(cfg: ModelConfig, p, x, out, valid):
+    """Shared post-mixer tail: post-norm, residual, FFN (dense or MoE).
+    Returns (x, counts): a MoE layer's ``moe_serve`` assignment counts
+    of the ``valid`` (B, S) rows, None for a dense FFN."""
     if cfg.post_block_norm:
         out = layers.apply_norm(cfg, p["post_norm_1"], out)
     x = x + out
     h = layers.apply_norm(cfg, p["norm_2"], x)
+    counts = None
     if "router" in p["ffn"]:
-        out, _ = moe.apply_moe(cfg, p["ffn"], h)
+        out, counts = moe.moe_serve(cfg, p["ffn"], h, valid)
     else:
         out = layers.apply_mlp(cfg, p["ffn"], h)
     if cfg.post_block_norm:
         out = layers.apply_norm(cfg, p["post_norm_2"], out)
-    return x + out
+    return x + out, counts
 
 
 def block_paged_decode(cfg: ModelConfig, p, x, cache, layer, cur_len,
-                       page_table, idx: int):
+                       page_table, idx: int, live=None):
     """One-token decode through one block against paged pools.
     x: (B,1,d); cache: stacked (L, P, ...) pools, this block's at index
-    ``layer``; cur_len: (B,); page_table: (B, NB)."""
+    ``layer``; cur_len: (B,); page_table: (B, NB); ``live`` (B,) bool,
+    the rows that decode a real token (all when None).  Returns (x,
+    cache, counts) — ``counts`` as ``_block_tail``'s, over the live
+    rows."""
     h = layers.apply_norm(cfg, p["norm_1"], x)
     if cfg.attention == "mla":
         out, cache = mla.mla_paged_decode_attention(cfg, p["mixer"], h,
@@ -434,14 +442,18 @@ def block_paged_decode(cfg: ModelConfig, p, x, cache, layer, cur_len,
         out, cache = attn.paged_decode_self_attention(
             cfg, p["mixer"], h, cache, layer, cur_len, page_table,
             window=layer_window(cfg, idx))
-    return _block_tail(cfg, p, x, out), cache
+    x, counts = _block_tail(cfg, p, x, out,
+                            None if live is None else live[:, None])
+    return x, cache, counts
 
 
 def block_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
                               valid_len, page_table, idx: int):
     """One prefill chunk through one block against paged pools.
     x: (B, T, d); cache: stacked (L, P, ...) pools, this block's at
-    index ``layer``; offset/valid_len: (B,); page_table: (B, NB)."""
+    index ``layer``; offset/valid_len: (B,); page_table: (B, NB).
+    Returns (x, cache, counts), counts over each row's first
+    ``valid_len`` tokens."""
     h = layers.apply_norm(cfg, p["norm_1"], x)
     if cfg.attention == "mla":
         out, cache = mla.mla_paged_prefill_chunk(cfg, p["mixer"], h, cache,
@@ -451,7 +463,10 @@ def block_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
         out, cache = attn.paged_prefill_chunk_self_attention(
             cfg, p["mixer"], h, cache, layer, offset, valid_len, page_table,
             window=layer_window(cfg, idx))
-    return _block_tail(cfg, p, x, out), cache
+    valid = jnp.arange(x.shape[1], dtype=jnp.int32)[None] \
+        < jnp.reshape(valid_len, (-1, 1))
+    x, counts = _block_tail(cfg, p, x, out, valid)
+    return x, cache, counts
 
 
 # -- prefill cache construction --------------------------------------------------------

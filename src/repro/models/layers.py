@@ -7,7 +7,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnConfig
 from repro.sharding.specs import annotate, shard
 
 
@@ -139,22 +139,56 @@ def logits_from_hidden(cfg: ModelConfig, p, x):
 
 # -- RoPE ----------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
+def rope_frequencies(head_dim: int, theta: float,
+                     yarn: Optional[YarnConfig] = None) -> jnp.ndarray:
+    """Inverse frequencies of the ``head_dim // 2`` rotated pairs.  With
+    ``yarn``: YaRN's blend (DeepSeek-V3's ``precompute_freqs_cis``) of
+    the frequencies divided by ``factor`` (interpolated) and the
+    original ones (extrapolated), by a linear ramp between the dims
+    that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings`` positions."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return inv
+
+    def corr_dim(rotations):
+        return head_dim * math.log(
+            yarn.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
+
+
+def yarn_softmax_factor(yarn: Optional[YarnConfig]) -> float:
+    """YaRN's attention-scale factor ``mscale**2``, with ``mscale =
+    0.1 * mscale_all_dim * ln(factor) + 1`` (1 without YaRN, or when
+    ``mscale_all_dim`` is 0)."""
+    if yarn is None or not yarn.mscale_all_dim or yarn.factor <= 1:
+        return 1.0
+    m = 0.1 * yarn.mscale_all_dim * math.log(yarn.factor) + 1.0
+    return m * m
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               m_rope_sections: Optional[Tuple[int, int, int]] = None):
+               m_rope_sections: Optional[Tuple[int, int, int]] = None,
+               yarn: Optional[YarnConfig] = None):
     """Rotary embedding.
 
     x: (B, S, H, hd). positions: (B, S) for standard RoPE, or (B, S, 3)
     for M-RoPE (qwen2-vl), where the half-dim is split into
     ``m_rope_sections`` chunks driven by the temporal/height/width
-    position streams respectively.
+    position streams respectively.  ``yarn``: YaRN-scaled frequencies
+    (see ``rope_frequencies``).
     """
     hd = x.shape[-1]
-    inv = rope_frequencies(hd, theta)          # (half,)
+    inv = rope_frequencies(hd, theta, yarn)    # (half,)
     if m_rope_sections is not None and positions.ndim == 3:
         secs = _scaled_sections(m_rope_sections, hd // 2)
         comp = jnp.concatenate(

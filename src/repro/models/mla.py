@@ -43,6 +43,14 @@ def _rms(x, scale, eps=1e-6):
     return (x32 * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
 
 
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``qk_head_dim ** -0.5``, times YaRN's ``mscale**2`` when the
+    config scales its rotary positions (DeepSeek-V3: ~1.874)."""
+    m = cfg.mla
+    return layers.yarn_softmax_factor(cfg.rope_scaling) / math.sqrt(
+        m.qk_nope_head_dim + m.qk_rope_head_dim)
+
+
 # -- params -------------------------------------------------------------------
 
 def init_mla(key, cfg: ModelConfig):
@@ -78,7 +86,8 @@ def _project_q(cfg: ModelConfig, p, x, positions):
     q = jnp.einsum("bsr,rhk->bshk", ql, p["wq_b"].astype(dt))
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = q[..., m.qk_nope_head_dim:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta,
+                               yarn=cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -90,7 +99,8 @@ def _project_kv_latent(cfg: ModelConfig, p, x, positions):
     latent = _rms(kv[..., :m.kv_lora_rank], p["kv_norm"])
     k_rope = kv[..., m.kv_lora_rank:]
     k_rope = layers.apply_rope(k_rope[:, :, None, :], positions,
-                               cfg.rope_theta)[:, :, 0, :]
+                               cfg.rope_theta,
+                               yarn=cfg.rope_scaling)[:, :, 0, :]
     return latent, k_rope
 
 
@@ -117,10 +127,9 @@ def mla_self_attention(cfg: ModelConfig, p, x, positions, *,
     k = shard(k, "batch", "kv_seq", "heads", "head_dim")
     v = shard(v, "batch", "kv_seq", "heads", "head_dim")
 
-    qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
     o = attention(cfg, q, k, v, q_pos=positions, kv_pos=positions,
                   causal=True, impl=impl, chunk=chunk,
-                  scale=1.0 / math.sqrt(qk_hd),
+                  scale=softmax_scale(cfg),
                   unroll=cfg.unroll_time_chunks,
                   causal_kv_trim=cfg.causal_kv_trim)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
@@ -197,13 +206,12 @@ def mla_prefill_chunk(cfg: ModelConfig, p, x, cache, offset, valid_len):
 
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"].astype(dt))
     q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)          # (B,T,H,r+rr)
-    qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
     mode = cfg.kv_quant
     kv_sc = cache["kv_scale"][:, :, None] if mode is not None else None
     kvx = kv_new[:, :, None, :]                                # (B,T,1,r+rr)
     kvc = cache["kv"][:, :, None, :]                           # (B,C,1,r+rr)
     ctx = pf_ops.prefill_attention(
-        q_eff, kvx, kvx, kvc, kvc, off, scale=1.0 / math.sqrt(qk_hd),
+        q_eff, kvx, kvx, kvc, kvc, off, scale=softmax_scale(cfg),
         v_width=m.kv_lora_rank, k_scale=kv_sc).astype(dt)      # (B,T,H,r)
 
     if mode is not None:
@@ -221,13 +229,31 @@ def mla_prefill_chunk(cfg: ModelConfig, p, x, cache, offset, valid_len):
 
 # -- paged (block pools + page-table indirection) ------------------------------
 
+def paged_row_width(cfg: ModelConfig) -> int:
+    """Lanes of a paged pool row: ``[latent | rope key]`` zero-padded to
+    a multiple of 128.  On a TPU an array whose last dim is not a
+    multiple of 128 takes a default layout that is not row-major (576
+    wide: pages innermost), which the kernels cannot read in place; a
+    padded row keeps the pool row-major, at the bytes its tiles would
+    take anyway."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // 128) * 128
+
+
+def _pad_row(x, width: int):
+    """Zero-pad the last dim of ``x`` to ``width`` (the added lanes
+    score exactly 0 against a zero-padded query)."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
 def init_paged_mla_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                         dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
-    """Physical page pool for one MLA layer: (P, page_size, r + rope)
-    ``[latent | rope key]`` rows.  Same one-page-id-per-position space
-    as ``attention.init_paged_kv_pools`` (page 0 = scratch)."""
-    m = cfg.mla
-    width = m.kv_lora_rank + m.qk_rope_head_dim
+    """Physical page pool for one MLA layer: (P, page_size, width)
+    ``[latent | rope key | 0]`` rows (``paged_row_width``).  Same
+    one-page-id-per-position space as ``attention.init_paged_kv_pools``
+    (page 0 = scratch)."""
+    width = paged_row_width(cfg)
     if cfg.kv_quant is not None:
         qdt = quant.quant_dtype(cfg.kv_quant)
         return {"kv": jnp.zeros((num_pages, page_size, width), qdt),
@@ -239,12 +265,12 @@ def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, layer,
                                cur_len, page_table, cache_impl: str = "auto"):
     """One-token absorbed-MLA decode against a *paged* latent cache.
 
-    x: (B, 1, d); cache: {"kv"} (L, P, page_size, r + rope) pools of a
-    layer stack, this layer at index ``layer``; cur_len: (B,);
+    x: (B, 1, d); cache: {"kv"} (L, P, page_size, W) pools of a layer
+    stack (``paged_row_width``), this layer at index ``layer``; cur_len: (B,);
     page_table: (B, NB) int32 (masked rows touch only the scratch
     page).  The absorbed trick carries over unchanged: the pool row is
-    both key and (``v_width``-truncated) value, viewed as
-    (L, P, page_size, 1, r + rope) for the kernels' KVH axis.
+    both key and (``v_width``-truncated) value, and the kernels take
+    the pool as one kv head without a head axis, in place.
     """
     from repro.kernels.cache_update import ops as cu_ops
     from repro.kernels.decode_attention import ops as da_ops
@@ -256,7 +282,9 @@ def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, layer,
 
     q_nope, q_rope = _project_q(cfg, p, x, positions)          # (B,1,H,*)
     latent_new, k_rope_new = _project_kv_latent(cfg, p, x, positions)
-    kv_new = jnp.concatenate([latent_new, k_rope_new], axis=-1)  # (B,1,r+rr)
+    width = paged_row_width(cfg)
+    kv_new = _pad_row(jnp.concatenate([latent_new, k_rope_new], axis=-1),
+                      width)                                   # (B,1,W)
 
     mode = cfg.kv_quant
     sc = None
@@ -271,12 +299,13 @@ def mla_paged_decode_attention(cfg: ModelConfig, p, x, cache, layer,
                                        impl=cache_impl)
 
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"].astype(dt))
-    q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)          # (B,1,H,r+rr)
-    qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
-    kv4 = kv[..., None, :]                                   # (L,P,ps,1,r+rr)
+    q_eff = _pad_row(jnp.concatenate([q_lat, q_rope], axis=-1),
+                     width)                                    # (B,1,H,W)
+    # The (L, P, ps, W) pool is read as it is stored: one kv head with
+    # no head axis (a (..., 1, W) view would relayout the pool).
     ctx = da_ops.decode_attention_paged(
-        q_eff, kv4, kv4, layer, page_table, cur,
-        scale=1.0 / math.sqrt(qk_hd), v_width=m.kv_lora_rank,
+        q_eff, kv, kv, layer, page_table, cur,
+        scale=softmax_scale(cfg), v_width=m.kv_lora_rank,
         k_scale=sc[..., None] if mode is not None else None
     ).astype(dt)                                               # (B,1,H,r)
 
@@ -292,8 +321,8 @@ def mla_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
     """One chunk of chunked prefill through one MLA layer, paged.
 
     Mirrors ``mla_prefill_chunk`` with the pool view in place of the
-    per-slot cache: layer ``layer`` of the stacked (L, P, page_size,
-    r + rope) pool; offset/valid_len are (B,) (rows with
+    per-slot cache: layer ``layer`` of the stacked (L, P, page_size, W)
+    pool; offset/valid_len are (B,) (rows with
     ``valid_len == 0`` are masked to the scratch page and discarded by
     the caller).  Attend first — the chunk's own rows arrive as
     separate operands — then scatter.
@@ -308,17 +337,19 @@ def mla_paged_prefill_chunk(cfg: ModelConfig, p, x, cache, layer, offset,
 
     q_nope, q_rope = _project_q(cfg, p, x, positions)          # (B,T,H,*)
     latent_new, k_rope_new = _project_kv_latent(cfg, p, x, positions)
-    kv_new = jnp.concatenate([latent_new, k_rope_new], axis=-1)  # (B,T,r+rr)
+    width = paged_row_width(cfg)
+    kv_new = _pad_row(jnp.concatenate([latent_new, k_rope_new], axis=-1),
+                      width)                                   # (B,T,W)
 
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"].astype(dt))
-    q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)          # (B,T,H,r+rr)
-    qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
+    q_eff = _pad_row(jnp.concatenate([q_lat, q_rope], axis=-1),
+                     width)                                    # (B,T,H,W)
     mode = cfg.kv_quant
-    kvx = kv_new[:, :, None, :]                                # (B,T,1,r+rr)
-    kvp = cache["kv"][..., None, :]                          # (L,P,ps,1,r+rr)
+    kvx = kv_new[:, :, None, :]                                # (B,T,1,W)
+    kvp = cache["kv"]                          # (L,P,ps,W): no head axis
     ctx = pf_ops.prefill_attention_paged(
         q_eff, kvx, kvx, kvp, kvp, layer, page_table, off,
-        scale=1.0 / math.sqrt(qk_hd), v_width=m.kv_lora_rank,
+        scale=softmax_scale(cfg), v_width=m.kv_lora_rank,
         k_scale=(cache["kv_scale"][..., None] if mode is not None
                  else None)).astype(dt)                        # (B,T,H,r)
 
@@ -394,8 +425,7 @@ def mla_decode_attention(cfg: ModelConfig, p, x, cache, cur_len,
     # absorb W_UK into the query: (B,1,H,nope) @ (r,H,nope) -> (B,1,H,r)
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"].astype(dt))
 
-    qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
-    scale = 1.0 / math.sqrt(qk_hd)
+    scale = softmax_scale(cfg)
     if impl == "flash":
         from repro.kernels.decode_attention import ops as da_ops
         # [q_lat | q_rope] . [latent | rope] is the absorbed score, so

@@ -613,10 +613,11 @@ class PagedServeFns(NamedTuple):
 def make_paged_serve_fns(cfg: ModelConfig):
     """Returns ``PagedServeFns(decode, prefill_chunk)``.
 
-    decode(params, caches, tokens (B,1), cur_len (B,), page_table)
-        -> (logits (B,V), caches)
+    decode(params, caches, tokens (B,1), cur_len (B,), page_table,
+           live=None) -> (logits (B,V), caches, moe_counts)
     prefill_chunk(params, caches, tokens (B,T), offset (B,),
-                  last_idx (B,), page_table) -> (logits (B,V), caches)
+                  last_idx (B,), page_table)
+        -> (logits (B,V), caches, moe_counts)
 
     ``caches`` are :func:`init_paged_caches` pools; ``page_table`` is
     (B, NB) int32 mapping each slot's logical blocks to physical pages
@@ -629,11 +630,18 @@ def make_paged_serve_fns(cfg: ModelConfig):
     passengers (idle or decoding): their ``valid_len`` clamps to 0, so
     they write nothing, and their logits row is garbage the engine
     discards.
+
+    ``moe_counts`` (held + 1,) int32 sums, over the MoE layers, the
+    assignments of the step's real tokens (the ``live`` decode rows,
+    all when None; each prefill row's first ``last_idx + 1``) to each
+    held expert, then all their routed assignments (``moe.moe_serve``);
+    a model without MoE returns ``[0]``.
     """
     if not supports_paged(cfg):
         raise ValueError(f"{cfg.name}: arch does not support paged KV "
                          "(needs all-attention layers, no encoder)")
     lay = unit_layout(cfg)
+    held = cfg.moe.held if cfg.moe is not None else 0
 
     def _run_stack(params, caches, x, run):
         """Shared prefix + units sweep for both paged steps.
@@ -643,50 +651,55 @@ def make_paged_serve_fns(cfg: ModelConfig):
         layer index, so the scan carries the unit pools and no step
         slices, copies or restacks a pool.  An unstacked leaf (a prefix
         layer, or the single unit) runs as a stack of one, a free
-        reshape."""
+        reshape.  Returns (hidden, caches, MoE counts)."""
         stack1 = lambda tree: jax.tree.map(lambda a: a[None], tree)
         unstack1 = lambda tree: jax.tree.map(lambda a: a[0], tree)
+        add = lambda tot, n: tot if n is None else tot + n
+        tot = jnp.zeros((held + 1,), jnp.int32)
         if lay.prefix:
             for i in lay.prefix:
-                x, c = run(x, params["prefix"][f"l{i}"],
-                           stack1(caches["prefix"][f"l{i}"]), 0, i)
+                x, c, n = run(x, params["prefix"][f"l{i}"],
+                              stack1(caches["prefix"][f"l{i}"]), 0, i)
                 caches["prefix"][f"l{i}"] = unstack1(c)
+                tot = add(tot, n)
 
-        def unit(xx, up, uc, u):
+        def unit(xx, up, uc, u, tot):
             uc = dict(uc)
             for r in range(lay.unit_len):
-                xx, uc[f"r{r}"] = run(xx, up[f"r{r}"], uc[f"r{r}"], u,
-                                      lay.prefix_len + r)
-            return xx, uc
+                xx, uc[f"r{r}"], n = run(xx, up[f"r{r}"], uc[f"r{r}"], u,
+                                         lay.prefix_len + r)
+                tot = add(tot, n)
+            return xx, uc, tot
 
         if lay.n_units == 1:
-            x, uc = unit(x, params["units"], stack1(caches["units"]), 0)
+            x, uc, tot = unit(x, params["units"], stack1(caches["units"]),
+                              0, tot)
             caches["units"] = unstack1(uc)
         elif cfg.scan_layers:
             def body(carry, up_u):
-                (xx, uc), (up, u) = carry, up_u
-                return unit(xx, up, uc, u), None
+                (xx, uc, t), (up, u) = carry, up_u
+                return unit(xx, up, uc, u, t), None
 
-            (x, caches["units"]), _ = jax.lax.scan(
-                body, (x, caches["units"]),
+            (x, caches["units"], tot), _ = jax.lax.scan(
+                body, (x, caches["units"], tot),
                 (params["units"], jnp.arange(lay.n_units, dtype=jnp.int32)))
         else:
             for u in range(lay.n_units):
-                x, caches["units"] = unit(
+                x, caches["units"], tot = unit(
                     x, jax.tree.map(lambda a: a[u], params["units"]),
-                    caches["units"], u)
-        return layers.apply_norm(cfg, params["final_norm"], x), caches
+                    caches["units"], u, tot)
+        return layers.apply_norm(cfg, params["final_norm"], x), caches, tot
 
-    def decode(params, caches, tokens, cur_len, page_table):
+    def decode(params, caches, tokens, cur_len, page_table, live=None):
         x = layers.embed_tokens(cfg, params["embed"], tokens)
 
         def run(xx, bp, c, layer, idx):
             return blocks.block_paged_decode(cfg, bp, xx, c, layer, cur_len,
-                                             page_table, idx)
+                                             page_table, idx, live)
 
-        x, caches = _run_stack(params, caches, x, run)
+        x, caches, moe = _run_stack(params, caches, x, run)
         logits = layers.logits_from_hidden(cfg, params["embed"], x)
-        return logits[:, 0], caches
+        return logits[:, 0], caches, moe
 
     def prefill_chunk(params, caches, tokens, offset, last_idx, page_table):
         last_idx = jnp.asarray(last_idx, jnp.int32)
@@ -697,12 +710,12 @@ def make_paged_serve_fns(cfg: ModelConfig):
             return blocks.block_paged_prefill_chunk(
                 cfg, bp, xx, c, layer, offset, valid_len, page_table, idx)
 
-        x, caches = _run_stack(params, caches, x, run)
+        x, caches, moe = _run_stack(params, caches, x, run)
         # per-row last real token (vector last_idx — batched admissions)
         idx = jnp.clip(last_idx, 0, x.shape[1] - 1)
         x_last = jnp.take_along_axis(
             x, idx[:, None, None].astype(jnp.int32), axis=1)   # (B,1,d)
         logits = layers.logits_from_hidden(cfg, params["embed"], x_last)
-        return logits[:, 0], caches
+        return logits[:, 0], caches, moe
 
     return PagedServeFns(decode, prefill_chunk)

@@ -23,6 +23,27 @@ dispatch; DESIGN.md §6):
 
 Dispatch FLOPs are therefore ~ active FLOPs x capacity_factor, never
 num_experts x dense FLOPs.
+
+Serving, and a layer that holds a share of the experts (expert
+parallelism: ``MoEConfig.held_experts``), take the drop-free path
+instead (``moe_serve``): the router scores every expert, and the layer
+computes every assignment to the experts it holds, so no capacity is
+shared across a batch and a row's output never depends on the rows
+beside it.  Assignments to experts held elsewhere add
+nothing here.  A step of ``GROUPED_MIN_ROWS`` rows or more, in a layer
+that holds more experts than a token picks (``top_k < held``: Kimi-K2,
+Jamba), runs each held expert over the rows routed to it, sorted by
+expert, as one grouped matmul (``lax.ragged_dot``; its work on a TPU
+follows the routed rows, and under the layer scan it reads each weight
+copied out of the stack).  Otherwise the held experts run as one
+batched FFN over every row of the step: below that many rows the FFN
+costs what reading the experts' weights costs, and a layer holding no
+more than ``top_k`` experts (one chip's share of DeepSeek-V3) would
+get a grouped buffer no smaller (a 64 x 32 prefill chunk there
+computes 32x its routed work, PERF.md).  That FFN sits under a
+``lax.cond`` that skips it when no row is routed to the held experts;
+on the device trace the conditional is the ``moe/experts`` scope's
+time, and the router's ops carry ``moe/route``.
 """
 from __future__ import annotations
 
@@ -42,17 +63,22 @@ from repro.sharding.specs import annotate, shard
 def init_moe(key, cfg: ModelConfig):
     """Router + expert bank (+ optional shared experts as one fused MLP)."""
     m = cfg.moe
-    d, e, ff = cfg.d_model, m.num_experts, m.ff_dim
+    d, e, ff, held = cfg.d_model, m.num_experts, m.ff_dim, m.held
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     p = {
-        "router": annotate(layers.dense_init(k1, (d, e)), "d_model", "experts"),
-        "w_up": annotate(_expert_init(k2, (e, d, ff)), "experts", "d_model",
-                         "ffn"),
-        "w_gate": annotate(_expert_init(k3, (e, d, ff)), "experts", "d_model",
-                           "ffn"),
-        "w_down": annotate(_expert_init(k4, (e, ff, d), in_axis=1), "experts",
-                           "ffn", "d_model"),
+        "router": annotate(layers.dense_init(k1, (d, e)), "d_model",
+                           "experts"),
+        "w_up": annotate(_expert_init(k2, (held, d, ff)), "experts",
+                         "d_model", "ffn"),
+        "w_gate": annotate(_expert_init(k3, (held, d, ff)), "experts",
+                           "d_model", "ffn"),
+        "w_down": annotate(_expert_init(k4, (held, ff, d), in_axis=1),
+                           "experts", "ffn", "d_model"),
     }
+    if m.topk_method == "noaux_tc":
+        # the per-expert correction bias: added to the scores for the
+        # choice of experts only, never to the gates
+        p["router_bias"] = annotate(jnp.zeros((e,), jnp.float32), None)
     if m.num_shared_experts:
         p["shared"] = layers.init_mlp(k5, cfg, d=d,
                                       ff=m.num_shared_experts * ff)
@@ -66,6 +92,13 @@ def _expert_init(key, shape, in_axis: int = 1):
 
 
 # -- static sizing ------------------------------------------------------------
+
+# Rows of a step from which the held experts run as a grouped matmul over
+# their routed rows (when top_k < held): about a TPU v5e's ridge, 197
+# TFLOP/s over 819 GB/s, the rows at which an FFN over every row stops
+# costing only its weights' reading.
+GROUPED_MIN_ROWS = 256
+
 
 def moe_groups(cfg: ModelConfig, batch: int) -> int:
     """Number of routing groups: the largest power-of-two divisor of the
@@ -85,19 +118,39 @@ def capacity(tokens_per_group: int, m: MoEConfig) -> int:
 
 def route(cfg: ModelConfig, p, xg: jnp.ndarray
           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Router probabilities and top-k choice.
+    """Router scores and top-k choice, in float32, over all
+    ``num_experts`` (``MoEConfig`` describes the two rules).
 
-    xg: (G, T, d) -> gates (G, T, k) fp32, expert ids (G, T, k) int32,
-    probs (G, T, E) fp32 (for the aux loss).
+    xg: (..., d) -> gates (..., k) fp32, expert ids (..., k) int32,
+    scores (..., E) fp32 (for the aux loss).
     """
     m = cfg.moe
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
+    logits = jnp.einsum("...d,de->...e", xg.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, m.top_k)
-    # deepseek/kimi renormalize the selected gate weights to sum to one
-    gates = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-    return gates, top_i.astype(jnp.int32), probs
+    if m.scoring_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    choose = scores
+    if "router_bias" in p:
+        choose = choose + p["router_bias"].astype(jnp.float32)
+    if m.n_group > 1:
+        grouped = choose.reshape(*choose.shape[:-1], m.n_group, -1)
+        if m.topk_method == "noaux_tc":
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+        else:
+            group_score = grouped.max(-1)
+        _, kept = jax.lax.top_k(group_score, m.topk_group)
+        keep = jax.nn.one_hot(kept, m.n_group, dtype=jnp.bool_).any(-2)
+        choose = jnp.where(keep[..., None], grouped,
+                           -jnp.inf).reshape(choose.shape)
+    _, top_i = jax.lax.top_k(choose, m.top_k)
+    top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+    if m.norm_topk_prob:
+        # deepseek/kimi renormalize the selected gate weights
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    gates = top_p * m.routed_scaling_factor
+    return gates, top_i.astype(jnp.int32), scores
 
 
 def aux_loss(probs: jnp.ndarray, top_i: jnp.ndarray, num_experts: int
@@ -142,10 +195,111 @@ def _dispatch_indices(top_i: jnp.ndarray, cap: int, num_experts: int):
 
 # -- the layer -------------------------------------------------------------------
 
+def held_gates(cfg: ModelConfig, gates, top_i):
+    """(..., held) gate of each row for each held expert (0 where the
+    row is not routed to it)."""
+    m = cfg.moe
+    ids = m.first_held_expert + jnp.arange(m.held, dtype=jnp.int32)
+    hit = top_i[..., None] == ids                     # (..., k, held)
+    return jnp.sum(jnp.where(hit, gates[..., None], 0.0), axis=-2), \
+        hit.any(-2)
+
+
+def _held_experts(p, x2, w):
+    """The held experts' part of the result: x2 (T, d), w (T, held)
+    gates -> (T, d).  Every row goes through every held expert; the
+    gates scale the hidden rows before the down projection, so rows
+    routed elsewhere add exactly zero."""
+    dt = x2.dtype
+    up = jnp.einsum("td,edf->etf", x2, p["w_up"].astype(dt))
+    gate = jnp.einsum("td,edf->etf", x2, p["w_gate"].astype(dt))
+    h = jax.nn.silu(gate) * up * w.T[:, :, None].astype(dt)
+    return jnp.einsum("etf,efd->td", h, p["w_down"].astype(dt))
+
+
+def _held_grouped(m: MoEConfig, p, x2, gates, top_i, ok, sizes):
+    """The held experts' part of the result, over the routed rows only:
+    x2 (T, d); gates, top_i (T, k); ok (T,) the valid rows; sizes
+    (held,) the valid rows' assignments to each held expert -> (T, d).
+    The assignments are sorted by held expert, the others last, and
+    past ``sum(sizes)`` the grouped matmul's rows are not read."""
+    dt = x2.dtype
+    k = top_i.shape[-1]
+    local = top_i - m.first_held_expert
+    keep = (local >= 0) & (local < m.held) & ok[:, None]
+    key = jnp.where(keep, local, m.held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    rows = order // k
+    xs = jnp.take(x2, rows, axis=0)
+    up = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), sizes)
+    gate = jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), sizes)
+    g = jnp.take(gates.reshape(-1), order)
+    h = jax.nn.silu(gate) * up * g[:, None].astype(dt)
+    y = jax.lax.ragged_dot(h, p["w_down"].astype(dt), sizes)
+    y = jnp.where(jnp.take(keep.reshape(-1), order)[:, None],
+                  y.astype(jnp.float32), 0.0)
+    return jnp.zeros(x2.shape, jnp.float32).at[rows].add(y).astype(dt)
+
+
+def moe_serve(cfg: ModelConfig, p, x: jnp.ndarray, valid=None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Drop-free MoE FFN.  x: (B, S, d); ``valid`` (B, S) bool marks the
+    rows that are real tokens (all when None); the others are routed to
+    no expert.  Returns (out (B, S, d), counts (held + 1,) int32): the
+    valid rows' assignments to each held expert, then all their routed
+    assignments.
+
+    With ``top_k < held`` and ``GROUPED_MIN_ROWS`` rows or more, the
+    held experts run over their routed rows as one grouped matmul;
+    otherwise over every row, under a ``lax.cond`` that skips them when
+    no valid row is routed to them.  On the device trace that
+    conditional is the experts' time (the ``moe/experts`` scope); a
+    gather of each expert's rows into a fixed buffer would need a
+    second branch for the batches that overflow it, and XLA hands each
+    branch a copy of the layer's expert weights out of the layer
+    stack."""
+    m = cfg.moe
+    b, s, d = x.shape
+    t = b * s
+    x2 = x.reshape(t, d)
+    with jax.named_scope("moe/route"):
+        gates, top_i, _ = route(cfg, p, x2)
+        w, hit = held_gates(cfg, gates, top_i)
+        ok = jnp.ones((t,), jnp.bool_) if valid is None \
+            else valid.reshape(t)
+        hit = hit & ok[:, None]
+        w = jnp.where(hit, w, 0.0)
+        counts = jnp.concatenate(
+            [jnp.sum(hit, axis=0, dtype=jnp.int32),
+             (jnp.sum(ok, dtype=jnp.int32) * m.top_k)[None]])
+    experts = {k: p[k] for k in ("w_up", "w_gate", "w_down")}
+    with jax.named_scope("moe/experts"):
+        if m.top_k < m.held and t >= GROUPED_MIN_ROWS:
+            out = _held_grouped(m, experts, x2, gates, top_i, ok,
+                                counts[:-1])
+        else:
+            out = jax.lax.cond(jnp.any(hit), _held_experts,
+                               lambda p_, x_, w_: jnp.zeros_like(x_),
+                               experts, x2, w)
+    out = out.reshape(b, s, d)
+    if "shared" in p:
+        out = out + layers.apply_mlp(cfg, p["shared"], x)
+    return out, counts
+
+
 def apply_moe(cfg: ModelConfig, p, x: jnp.ndarray
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """MoE FFN. x: (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
+    """MoE FFN. x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
+
+    Capacity-limited sort-based dispatch for a layer that holds every
+    expert; a layer that holds a share runs drop-free (``moe_serve``).
+    """
     m = cfg.moe
+    if m.held < m.num_experts:
+        out, _ = moe_serve(cfg, p, x)
+        _, top_i, probs = route(cfg, p, x.reshape(1, -1, x.shape[-1]))
+        return out, aux_loss(probs, top_i, m.num_experts).astype(
+            jnp.float32)
     b, s, d = x.shape
     dt = x.dtype
     g = moe_groups(cfg, b)

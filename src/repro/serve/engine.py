@@ -90,11 +90,15 @@ one phase annotation; the phases tile the loop body:
 The step programs are named ``serve_decode``, ``serve_prefill_chunk``
 and ``serve_prefill`` (``jit_serve_decode`` in a trace).
 
-Known semantic caveat: MoE layers route with cross-batch capacity
-limits, so under continuous batching a request's tokens can be dropped
-differently depending on its slot neighbours; dense/GQA/MLA/SSM archs
-decode each row independently (slot refill leaks no state — see
-tests/test_serve_continuous.py for the byte-parity gate).
+Every step decodes each row independently, MoE layers included: the
+serve path routes drop-free (``moe.moe_serve``), with no capacity
+shared across the batch, so slot refill leaks no state and a request's
+tokens do not depend on its slot neighbours (see
+tests/test_serve_continuous.py for the byte-parity gate).  The paged
+steps also count, on the device, the assignments of their real
+tokens to each held expert, into a running total donated with the
+pools; ``stats()`` reads it as ``moe_held_assignments`` (per held
+expert) and ``moe_assignments`` (all routed).
 """
 from __future__ import annotations
 
@@ -164,13 +168,18 @@ def make_paged_decode_fn(cfg: ModelConfig, greedy: bool = True,
     where ``ok[j]`` is True iff row ``j``'s logits were all finite —
     the engine's NaN/Inf quarantine reads it for the rows it actually
     consumes (dead/masked rows attend the scratch page and may be
-    legitimately garbage)."""
+    legitimately garbage).  ``live`` (B,) marks the rows that decode a
+    real token; given ``moe_total``, a fourth output adds the step's
+    MoE counts of the live rows to it (``model.make_paged_serve_fns``)."""
     decode = model_mod.make_paged_serve_fns(cfg).decode
 
-    def decode_fn(params, caches, tokens, cur_len, page_table, key=None):
-        logits, caches = decode(params, caches, tokens, cur_len, page_table)
+    def decode_fn(params, caches, tokens, cur_len, page_table, key=None,
+                  live=None, moe_total=None):
+        logits, caches, moe = decode(params, caches, tokens, cur_len,
+                                     page_table, live)
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
-        return _pick(logits, greedy, temperature, key)[:, None], ok, caches
+        out = (_pick(logits, greedy, temperature, key)[:, None], ok, caches)
+        return out if moe_total is None else out + (moe_total + moe,)
 
     return decode_fn
 
@@ -183,15 +192,17 @@ def make_paged_prefill_chunk_fn(cfg: ModelConfig, greedy: bool = True,
     ``(tokens (B,), ok (B,), caches)`` — only rows finishing their
     prefill this step use their token, and ``ok`` flags finite logits
     per row for the quarantine check (passenger rows' flags are
-    meaningless and ignored)."""
+    meaningless and ignored).  ``moe_total`` as for decode, counting
+    each row's chunk tokens."""
     pf = model_mod.make_paged_serve_fns(cfg).prefill_chunk
 
     def chunk_fn(params, caches, tokens, offset, last_idx, page_table,
-                 key=None):
-        logits, caches = pf(params, caches, tokens, offset, last_idx,
-                            page_table)
+                 key=None, moe_total=None):
+        logits, caches, moe = pf(params, caches, tokens, offset, last_idx,
+                                 page_table)
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
-        return _pick(logits, greedy, temperature, key), ok, caches
+        out = (_pick(logits, greedy, temperature, key), ok, caches)
+        return out if moe_total is None else out + (moe_total + moe,)
 
     return chunk_fn
 
@@ -671,14 +682,35 @@ class ServeEngine:
                 (batch_size, self._pages_per_slot), np.int32)
             self._slot_pages: List[List[int]] = \
                 [[] for _ in range(batch_size)]
-            self._paged_decode = jax.jit(
+            # donated with the pools: the steps add their MoE counts
+            # to it on the device, and only stats() reads it back
+            self._moe_total = jnp.zeros(
+                ((cfg.moe.held if cfg.moe is not None else 0) + 1,),
+                jnp.int32)
+            self._paged_decode_step = jax.jit(
                 self._counted("decode",
                               make_paged_decode_fn(cfg, **sample_kw)),
-                donate_argnums=1)
-            self._paged_prefill_chunk_fn = jax.jit(
+                donate_argnums=(1, 7))
+            self._paged_prefill_chunk_step = jax.jit(
                 self._counted("prefill_chunk",
                               make_paged_prefill_chunk_fn(cfg, **sample_kw)),
-                donate_argnums=1)
+                donate_argnums=(1, 7))
+
+    def _paged_decode(self, params, caches, tokens, cur_len, page_table,
+                      key, live):
+        """One paged decode step; returns (tokens, ok, caches)."""
+        tok, ok, caches, self._moe_total = self._paged_decode_step(
+            params, caches, tokens, cur_len, page_table, key, live,
+            self._moe_total)
+        return tok, ok, caches
+
+    def _paged_prefill_chunk_fn(self, params, caches, tokens, offset,
+                                last_idx, page_table, key):
+        """One paged prefill chunk; returns (tokens, ok, caches)."""
+        tok, ok, caches, self._moe_total = self._paged_prefill_chunk_step(
+            params, caches, tokens, offset, last_idx, page_table, key,
+            self._moe_total)
+        return tok, ok, caches
 
     def _counted(self, name: str, fn):
         """``fn`` counted in ``compile_counts[name]`` at each trace, and
@@ -905,6 +937,11 @@ class ServeEngine:
             "decode_steps": self.decode_steps,
             "decode_row_steps": self.decode_row_steps,
         }
+        if self.cfg.moe is not None and self._pool is not None:
+            # summed over the MoE layers: per held expert, then all routed
+            tot = np.asarray(self._moe_total)
+            s["moe_held_assignments"] = tot[:-1].tolist()
+            s["moe_assignments"] = int(tot[-1])
         if self.session is not None:
             s["measurement"] = self.session.stats()
         cache_s: Dict[str, Any] = {
@@ -1905,6 +1942,7 @@ class ServeEngine:
                     for j in live:
                         mask[j] = 1
                     pt_dec = jnp.asarray(self._page_table * mask)
+                    live_dev = jnp.asarray(mask[:, 0] > 0)
                     tok_dev = jnp.asarray(tokens)
                     pos_dev = jnp.asarray(pos)
                     outs = []
@@ -1913,7 +1951,7 @@ class ServeEngine:
                     for _ in range(steps):
                         tok_dev, ok_dev, caches = self._paged_decode(
                             self.params, caches, tok_dev, pos_dev, pt_dec,
-                            self._next_key())
+                            self._next_key(), live_dev)
                         outs.append(tok_dev)
                         oks.append(ok_dev)
                         pos_dev = pos_dev + 1

@@ -166,12 +166,8 @@ def test_decode_compiled_path_rejects_untileable_blocks():
 # -- model-level: flash vs dense across cache families -------------------------
 
 def _fp32(arch):
-    cfg = dataclasses.replace(configs.get_config(arch, reduced=True),
-                              dtype="float32")
-    if cfg.moe is not None:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))
-    return cfg
+    return dataclasses.replace(configs.get_config(arch, reduced=True),
+                               dtype="float32")
 
 
 # gemma2 = sliding-window ring + softcap; deepseek = MLA latent cache

@@ -16,6 +16,7 @@ every other layer, and the pages beside the layer's scratch page,
 untouched.
 """
 import functools
+import importlib
 import math
 
 import jax
@@ -338,3 +339,73 @@ def test_paged_prefill_wrapper_layout():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(want).transpose(0, 2, 1, 3, 4)
         .reshape(b, t, h, hd), rtol=2e-6, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# headless pools (one kv head stored without its axis: the MLA latent)
+# and query blocks
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_layers,layer", STACKS)
+def test_paged_decode_headless_pool_matches_head_axis(n_layers, layer):
+    """A (L, P, page_size, hd) pool read in place gives, bitwise, what
+    the same pool viewed with a kv-head axis of one gives, in the
+    kernel (interpret), its lax twin and the wrapper."""
+    q, k, _, pt, lens = _decode_case(110, kvh=1, g=8, alias=True,
+                                     n_layers=n_layers)
+    kw = dict(scale=0.5, v_width=24)
+    pool = k[..., 0, :]
+    for fn in (functools.partial(da_ops.decode_attention_paged_pallas,
+                                 interpret=True),
+               da_ops.decode_attention_paged_lax):
+        got = fn(q, pool, pool, layer, pt, lens, **kw)
+        want = fn(q, k, k, layer, pt, lens, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    b, _, g, hd = q.shape
+    out = da_ops.decode_attention_paged(q.reshape(b, 1, g, hd), pool, pool,
+                                        layer, pt, lens, impl="lax", **kw)
+    assert out.shape == (b, 1, g, 24)
+
+
+@pytest.mark.parametrize("n_layers,layer", STACKS)
+def test_paged_prefill_headless_pool_matches_head_axis(n_layers, layer):
+    q, kx, _, kc, _, pt, offs = _prefill_case(120, kvh=1, g=8, alias=True,
+                                              n_layers=n_layers)
+    kw = dict(scale=0.5, v_width=24)
+    pool = kc[..., 0, :]
+    for fn in (functools.partial(pf_ops.prefill_attention_paged_pallas,
+                                 interpret=True),
+               pf_ops.prefill_attention_paged_lax):
+        got = fn(q, kx, kx, pool, pool, layer, pt, offs, **kw)
+        want = fn(q, kx, kx, kc, kc, layer, pt, offs, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("window", [None, 11])
+def test_paged_prefill_query_blocks_interpret_bitwise(monkeypatch, window):
+    """A chunk whose rows overflow a grid step's budget runs in blocks
+    of queries, each sweeping the prefix and the chunk: the oracle's
+    result over the whole chunk, to 1 ulp (the CPU interpret-mode
+    matmul picks its contraction order by the block's row count)."""
+    pa = importlib.import_module(
+        "repro.kernels.prefill_attention.prefill_attention")
+    q, kx, vx, kc, vc, pt, offs = _prefill_case(130, n_layers=3)
+    _, kvh, t, g, hd = q.shape
+    monkeypatch.setattr(pa, "QUERY_BLOCK_ELEMS",
+                        t // 4 * kvh * g * (hd + vc.shape[-1]))
+    assert pa.query_block(t, kvh, g, hd, vc.shape[-1]) == t // 4
+    kw = dict(window=window, scale=1.0 / math.sqrt(32))
+    got = pf_ops.prefill_attention_paged_pallas(q, kx, vx, kc, vc, 1, pt,
+                                                offs, interpret=True, **kw)
+    want = prefill_attention_paged_ref(q, kx, vx, kc, vc, 1, pt, offs, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_query_block_keeps_dense_chunks_whole():
+    query_block = importlib.import_module(
+        "repro.kernels.prefill_attention.prefill_attention").query_block
+    assert query_block(32, 8, 2, 128, 128) == 32      # qwen3-0.6b
+    assert query_block(32, 16, 1, 128, 128) == 32     # olmo-1b
+    assert query_block(32, 1, 128, 640, 512) == 8     # deepseek-v3 MLA

@@ -91,7 +91,7 @@ def _decode_loop(cfg):
     def step(params, caches, tokens, cur_len, page_table):
         def run(xx, bp, c, idx):
             return blocks.block_paged_decode(cfg, bp, xx, c, 0, cur_len,
-                                             page_table, idx)
+                                             page_table, idx)[:2]
 
         x = layers.embed_tokens(cfg, params["embed"], tokens)
         x, caches = _per_layer(cfg, params, caches, x, run)
@@ -106,7 +106,7 @@ def _chunk_loop(cfg):
 
         def run(xx, bp, c, idx):
             return blocks.block_paged_prefill_chunk(
-                cfg, bp, xx, c, 0, offset, valid_len, page_table, idx)
+                cfg, bp, xx, c, 0, offset, valid_len, page_table, idx)[:2]
 
         x = layers.embed_tokens(cfg, params["embed"], tokens)
         x, caches = _per_layer(cfg, params, caches, x, run)
@@ -132,7 +132,7 @@ def test_paged_decode_matches_per_layer_loop(case):
     tokens = jnp.asarray([[5], [17], [3]], jnp.int32)
     cur = jnp.asarray([0, 9, 21], jnp.int32)
     step = jax.jit(M.make_paged_serve_fns(cfg).decode)
-    got = step(params, pools, tokens, cur, pt)
+    got = step(params, pools, tokens, cur, pt)[:2]   # logits, pools
     want = jax.jit(_decode_loop(cfg))(params, pools, tokens, cur, pt)
     _assert_same(got, want)
 
@@ -145,7 +145,7 @@ def test_paged_chunk_matches_per_layer_loop(case):
     offset = jnp.asarray([0, 12, 4], jnp.int32)
     last_idx = jnp.asarray([T - 1, 2, -1], jnp.int32)   # full, part, idle
     step = jax.jit(M.make_paged_serve_fns(cfg).prefill_chunk)
-    got = step(params, pools, tokens, offset, last_idx, pt)
+    got = step(params, pools, tokens, offset, last_idx, pt)[:2]
     want = jax.jit(_chunk_loop(cfg))(params, pools, tokens, offset,
                                      last_idx, pt)
     _assert_same(got, want)

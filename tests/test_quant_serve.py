@@ -45,25 +45,23 @@ DRIFT_GATE = {"int8": 0.10, "fp8_e4m3": 0.20}
 
 
 def fp32(arch):
-    cfg = dataclasses.replace(configs.get_config(arch, reduced=True),
-                              dtype="float32")
-    if cfg.moe is not None:
-        # drift gates measure quantization, not MoE token-drop noise
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))
-    return cfg
+    return dataclasses.replace(configs.get_config(arch, reduced=True),
+                               dtype="float32")
 
 
-def serve_logits(cfg, params, tokens):
-    """Prefill + two decode steps (write-then-read of quantized rows)."""
+def serve_logits(cfg, params, tokens, nxt=None):
+    """Prefill + two decode steps (write-then-read of quantized rows).
+    The second step feeds ``nxt`` (default: the first step's greedy
+    token), so that two caches are compared on the same tokens."""
     prefill, decode, _ = M.make_serve_fns(cfg, cache_dtype=jnp.float32)
     _, caches = jax.jit(lambda p, b: prefill(p, b, T + 4))(
         params, {"tokens": tokens[:, :T - 1]})
     lg, caches = jax.jit(decode)(params, caches, tokens[:, T - 1:T],
                                  jnp.asarray(T - 1, jnp.int32))
-    nxt = jnp.argmax(lg, -1)[:, None].astype(tokens.dtype)
+    if nxt is None:
+        nxt = jnp.argmax(lg, -1)[:, None].astype(tokens.dtype)
     lg2, _ = jax.jit(decode)(params, caches, nxt, jnp.asarray(T, jnp.int32))
-    return np.asarray(lg), np.asarray(lg2)
+    return np.asarray(lg), np.asarray(lg2), nxt
 
 
 @pytest.mark.parametrize("mode", MODES)
@@ -74,9 +72,9 @@ def test_quant_logit_drift_gate(arch, mode):
     params, _ = M.init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,
                                 cfg.vocab_size)
-    ref1, ref2 = serve_logits(cfg, params, tokens)
-    q1, q2 = serve_logits(dataclasses.replace(cfg, kv_quant=mode), params,
-                          tokens)
+    ref1, ref2, nxt = serve_logits(cfg, params, tokens)
+    q1, q2, _ = serve_logits(dataclasses.replace(cfg, kv_quant=mode), params,
+                             tokens, nxt)
     assert np.isfinite(q1).all() and np.isfinite(q2).all()
     bound = DRIFT_GATE[mode] * max(float(np.max(np.abs(ref1))), 1.0)
     assert float(np.max(np.abs(q1 - ref1))) < bound

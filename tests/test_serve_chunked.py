@@ -37,12 +37,8 @@ def rng(i):
 
 
 def _fp32(arch):
-    cfg = dataclasses.replace(configs.get_config(arch, reduced=True),
-                              dtype="float32")
-    if cfg.moe is not None:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))
-    return cfg
+    return dataclasses.replace(configs.get_config(arch, reduced=True),
+                               dtype="float32")
 
 
 def mk(reqs):

@@ -60,6 +60,26 @@ def test_continuous_byte_identical_to_single_request(smollm):
         assert len(done[i].out) == n
 
 
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_moe_continuous_byte_identical_to_single_request(layout):
+    """The same gate for a MoE model (DeepSeek-V3's routing at its
+    reduced size): serving routes drop-free, so no capacity couples a
+    row to its slot neighbours."""
+    cfg = dataclasses.replace(
+        configs.get_config("deepseek-v3-671b", reduced=True),
+        dtype="float32", prefill_chunk=8)
+    params, _ = M.init_params(jax.random.PRNGKey(0), cfg)
+    kw = dict(max_len=64, kv_layout=layout)
+    if layout == "paged":
+        kw.update(kv_page_size=8)
+    done = ServeEngine(cfg, params, batch_size=3, **kw).generate(mk(MIXED))
+    ref_eng = ServeEngine(cfg, params, batch_size=1, **kw)
+    for i, (prompt, n) in enumerate(MIXED):
+        ref = ref_eng.generate(mk([(prompt, n)]))[0]
+        assert done[i].out == ref.out, (i, done[i].out, ref.out)
+        assert len(done[i].out) == n
+
+
 def test_slot_refill_leaks_no_kv(smollm):
     """A request decoded in a freshly-refilled slot matches its own
     solo run regardless of which request occupied the slot before —

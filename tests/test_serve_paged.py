@@ -33,15 +33,9 @@ ARCHS = ["smollm-135m", "gemma2-27b", "deepseek-v3-671b"]
 
 
 def _fp32(arch, **over):
-    cfg = dataclasses.replace(configs.get_config(arch, reduced=True),
-                              dtype="float32", **over)
-    if cfg.moe is not None:
-        # effectively dropless: capacity-limited token dropping depends
-        # on batch composition, which differs between the engines and
-        # runs being compared
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=64.0))
-    return cfg
+    # MoE layers serve drop-free: no capacity couples a row to the batch
+    return dataclasses.replace(configs.get_config(arch, reduced=True),
+                               dtype="float32", **over)
 
 
 @pytest.fixture(scope="module", params=ARCHS)

@@ -143,8 +143,8 @@ def test_twins_without_a_session(traced, tmp_path):
 
 def test_step_programs_carry_their_names(traced):
     eng = traced["eng"]
-    assert eng._paged_decode.__name__ == "serve_decode"
-    assert eng._paged_prefill_chunk_fn.__name__ == "serve_prefill_chunk"
+    assert eng._paged_decode_step.__name__ == "serve_decode"
+    assert eng._paged_prefill_chunk_step.__name__ == "serve_prefill_chunk"
     assert eng._prefill.__name__ == "serve_prefill"
     assert eng._decode.__name__ == "serve_decode"
     names = {n for evs in traced["events"].values() for n, _, _ in evs}

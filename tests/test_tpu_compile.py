@@ -230,25 +230,38 @@ def test_serve_steps_hold_every_kernel(one_chip, pallas_dispatch, layout):
 GUARD_PAGES = 4097
 
 
-@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+# The archs at their published widths, cut in depth so that the units
+# run under the layer scan: 2 layers, or DeepSeek-V3's 3 dense layers
+# and 2 MoE layers (8 experts held, one eighth of the vocabulary), with
+# the pages that make one layer's pool larger than the chip's VMEM.
+POOL_CUTS = {"qwen3-0.6b": (dict(num_layers=2), GUARD_PAGES),
+             "olmo-1b": (dict(num_layers=2), GUARD_PAGES),
+             "deepseek-v3-671b": (dict(num_layers=5, vocab_size=16160,
+                                       mtp=False, moe={"held_experts": 8}),
+                                  2 * GUARD_PAGES - 1)}
+
+
+@pytest.mark.parametrize("arch", sorted(POOL_CUTS))
 def test_paged_steps_keep_the_pool_in_place(one_chip, pallas_dispatch,
                                             arch):
     """The compiled paged decode and chunk programs at the arch's
-    published widths (2 layers, so the units run under the layer scan)
-    hold no copy, slice or update whose shape is a whole layer's pool
-    or the stacked pool: the kernels read and write the stacked pool in
-    place, aliased from input to output."""
-    cfg = dataclasses.replace(configs.get_config(arch), num_layers=2,
-                              param_dtype="bfloat16")
+    published widths hold no copy, slice or update whose shape is a
+    whole layer's pool or the stacked pool, and no temporary buffer as
+    large as one layer's pool: the kernels read and write the stacked
+    pool in place, aliased from input to output."""
+    cut, pages = POOL_CUTS[arch]
+    cfg = configs.get_config(arch, param_dtype="bfloat16", **cut)
     params = jax.eval_shape(
         lambda: model_mod.init_params(jax.random.PRNGKey(0), cfg)[0])
     pools = jax.eval_shape(
-        lambda: model_mod.init_paged_caches(cfg, GUARD_PAGES, PS))
-    leaves = jax.tree.leaves(pools)
-    assert leaves[0].shape[0] == 2                  # stacked on "layers"
-    # the stacked pool, one layer's pool, and the stack as one pool of
-    # L * P pages, as the kernels see it
-    shapes = {tuple(a.shape) for a in leaves} | \
+        lambda: model_mod.init_paged_caches(cfg, pages, PS))
+    leaves = jax.tree.leaves(pools["units"])
+    prefix = jax.tree.leaves(pools.get("prefix", {}))
+    assert all(a.shape[0] == 2 for a in leaves)     # stacked on "layers"
+    layer_pool = min(a.size // 2 * a.dtype.itemsize for a in leaves)
+    # the stacked pool, one layer's pool (a prefix layer's too), and the
+    # stack as one pool of L * P pages, as the kernels see it
+    shapes = {tuple(a.shape) for a in leaves + prefix} | \
         {tuple(a.shape[1:]) for a in leaves} | \
         {(a.shape[0] * a.shape[1],) + tuple(a.shape[2:]) for a in leaves}
     rows = S((B,), I32)
@@ -263,7 +276,10 @@ def test_paged_steps_keep_the_pool_in_place(one_chip, pallas_dispatch,
     for name, (fn, shapes_in) in steps.items():
         args = [jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=one_chip), a) for a in shapes_in]
-        text = jax.jit(fn, donate_argnums=1).lower(*args).compile().as_text()
+        compiled = jax.jit(fn, donate_argnums=1).lower(*args).compile()
+        text = compiled.as_text()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < layer_pool, (name, temp, layer_pool)
         moved = [(kind, dims) for dims, kind in op.findall(text)
                  if kind in ("copy", "copy-done", "dynamic-slice",
                              "dynamic-update-slice", "fusion", "slice",
@@ -271,3 +287,39 @@ def test_paged_steps_keep_the_pool_in_place(one_chip, pallas_dispatch,
                  and tuple(int(d) for d in dims.split(",") if d) in shapes]
         assert moved == [], (name, moved)
         assert "paged_cache_update" in text, name
+
+
+def test_grouped_experts_compile_over_the_routed_rows(one_chip):
+    """Kimi-K2's expert layer at its published widths (384 experts of
+    2048, top-8), 16 experts held, over a 64 x 32 prefill chunk under a
+    scan of 2 layers: the held experts run as a grouped matmul over the
+    routed rows (``moe.GROUPED_MIN_ROWS``), and the program holds no
+    temporary buffer as large as one layer's held experts.  (The
+    grouped matmul takes each weight of the layer sliced out of the
+    stack, one at a time: a copy the batched FFN does not make.)"""
+    from repro.models import moe
+    cfg = configs.get_config("kimi-k2-1t-a32b", param_dtype="bfloat16",
+                             num_layers=2, moe={"held_experts": 16})
+    m = cfg.moe
+    d, ff, held, layers_ = cfg.d_model, m.ff_dim, m.held, 2
+    assert m.top_k < held and B * T >= moe.GROUPED_MIN_ROWS
+    stack = {"router": S((layers_, d, m.num_experts), BF16),
+             "w_up": S((layers_, held, d, ff), BF16),
+             "w_gate": S((layers_, held, d, ff), BF16),
+             "w_down": S((layers_, held, ff, d), BF16)}
+
+    def layers_fn(stack, x):
+        def one(x, p):
+            out, counts = moe.moe_serve(cfg, p, x)
+            return x + out, counts
+        return jax.lax.scan(one, x, stack)
+
+    compiled = jax.jit(layers_fn).lower(
+        *[jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), a)
+          for a in (stack, S((B, T, d), BF16))]).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    experts = 3 * held * d * ff * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < experts, (temp, experts)
